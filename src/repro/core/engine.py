@@ -76,6 +76,10 @@ from repro.core import ozimmu, splitting
 __all__ = ["MatmulEngine", "make_engine", "PresplitWeight"]
 
 _NATIVE = {"bf16": jnp.bfloat16, "f32": jnp.float32, "f64": jnp.float64}
+# the f32/f64 specs are references: on a TPU the default precision would
+# run them as one-pass bf16 products, so they ask for full precision
+_NATIVE_PRECISION = {"bf16": None, "f32": jax.lax.Precision.HIGHEST,
+                     "f64": jax.lax.Precision.HIGHEST}
 
 
 class PresplitWeight:
@@ -178,6 +182,13 @@ class MatmulEngine:
             not in _NATIVE
 
     @property
+    def native_precision(self) -> Optional[jax.lax.Precision]:
+        """``lax`` precision of a native spec's dots: HIGHEST for the
+        ``f32``/``f64`` references, None (backend default) for ``bf16``
+        and for ozimmu specs."""
+        return _NATIVE_PRECISION.get(self.spec)
+
+    @property
     def ozimmu_config(self) -> Optional[ozimmu.OzimmuConfig]:
         return ozimmu.parse_spec(self.spec) if self.is_ozimmu else None
 
@@ -212,7 +223,7 @@ class MatmulEngine:
             acc = jnp.float64 if dt == jnp.float64 else jnp.float32
             out = jax.lax.dot_general(
                 lhs.astype(dt), rhs.astype(dt), dimension_numbers,
-                preferred_element_type=acc)
+                precision=self.native_precision, preferred_element_type=acc)
             return out.astype(out_dtype)
 
         cfg = self.ozimmu_config

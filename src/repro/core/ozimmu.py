@@ -198,12 +198,15 @@ def variant_name(cfg: "OzimmuConfig") -> str:
 
 
 def _record_emulation(cfg: "OzimmuConfig", a, p: int,
-                      presplit: bool) -> None:
+                      presplit: bool, sharded: bool) -> None:
     """Mirror one resolved contraction into the metrics registry.
 
     Called from ``_bmm_impl`` after the config is fully canonical (fast2
     tied, accumulator downgraded, auto-k resolved to a concrete k), so
-    the recorded counts are exactly what executes.  Host-side only: runs
+    the recorded counts are exactly what executes; the ``mesh`` label
+    names the mesh axis only when the sharded path runs (``sharded``),
+    so a mesh-native spec that fell back to the local path shows
+    ``mesh="none"``.  Host-side only: runs
     once per eager call or per jit *trace* — a compiled step that traced
     through here replays the same contraction on every execution, so
     trace-time counts are per-execution counts.  Costs come from the
@@ -221,7 +224,8 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
         variant=variant_name(cfg), k=cfg.k,
         path=("fused" if cfg.use_pallas == "fused"
               else "pallas" if cfg.use_pallas else "xla"),
-        mesh=cfg.mesh_axis or "none", presplit=int(presplit))
+        mesh=cfg.mesh_axis if sharded else "none",
+        presplit=int(presplit))
     reg = _obs.get_registry()
     reg.inc("emulation.calls", 1, **labels)
     reg.inc("emulation.int8_gemms", batch * pl.int8_gemms, **labels)
@@ -259,6 +263,7 @@ def parse_spec(spec: str) -> OzimmuConfig:
     ``"...@model/df32"`` selects the compensated
     partial-accumulator reduction instead (see docs/distributed.md).
     """
+    full_spec = spec
     mesh_axis, mesh_reduce = None, "int32"
     if "@" in spec:
         spec, mesh = spec.split("@", 1)
@@ -320,6 +325,13 @@ def parse_spec(spec: str) -> OzimmuConfig:
                          f"— a fixed slice count leaves the planner "
                          f"nothing to resolve; got {name!r} with "
                          f"k={kstr or cfg.k}, want e.g. {name}-auto:prob")
+    if use_pallas == "fused" and accum_dtype == "f64" \
+            and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"engine spec {full_spec!r} cannot run on a TPU: its ':fused' "
+            f"kernels would need an f64 accumulator (and, for oz2, an "
+            f"int64 ladder word), which Mosaic does not compile; use "
+            f"':df32' or drop ':fused'")
     return canonical_fast2(cfg.with_(
         k=cfg.k if (auto_k or not kstr) else int(kstr),
         auto_k=auto_k, accum_dtype=accum_dtype,
@@ -433,9 +445,8 @@ def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
 
     Cached so repeated *eager* mesh-native contractions reuse one
     compiled entry instead of re-wrapping a fresh closure in ``jax.jit``
-    per call (which would defeat jit's own cache); the jit is needed at
-    all because eager shard_map is NotImplemented for some collective/dot
-    patterns on older JAX.  Inside an outer jit it inlines for free.
+    per call (which would defeat jit's own cache).  Inside an outer jit
+    it inlines for free.
 
     ``presplit_meta`` (serving): ``(beta, has_base, has_gbase, signmag)``
     of a frozen B-side Split — the callable then takes ``(a, (digits,
@@ -447,7 +458,7 @@ def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed import collectives, compat
+    from repro.distributed import collectives
 
     axis = cfg.mesh_axis
     a_spec = P(*((None,) * (nb + 1) + (axis,)))
@@ -488,9 +499,9 @@ def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
                 return collectives.psum_df32(part, axis).to_float(out_dtype)
             return collectives.psum_compensated(part, axis).astype(out_dtype)
 
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, axis_names={axis},
-                                    check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, axis_names={axis},
+                                 check_vma=False))
 
 
 def _bmm_sharded(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, mesh,
@@ -532,8 +543,7 @@ def _mesh_for(cfg: OzimmuConfig, n: int):
     the caller falls back to the single-device emulation under GSPMD)."""
     if cfg.mesh_axis is None:
         return None
-    from repro.distributed import compat
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or cfg.mesh_axis not in mesh.axis_names:
         return None
     size = dict(mesh.shape)[cfg.mesh_axis]
@@ -614,9 +624,10 @@ def _bmm_impl(a: jax.Array, b: jax.Array, cfg: OzimmuConfig,
             cfg = cfg.with_(k=_plan.auto_k(a, b, cfg), auto_k=False)
     if rhs_presplit is not None:
         _check_presplit(a, b.shape, cfg, rhs_presplit)
-    if _obs.enabled():
-        _record_emulation(cfg, a, b.shape[-1], rhs_presplit is not None)
     mesh = _mesh_for(cfg, a.shape[-1])
+    if _obs.enabled():
+        _record_emulation(cfg, a, b.shape[-1], rhs_presplit is not None,
+                          sharded=mesh is not None)
     if mesh is not None:
         return _bmm_sharded(a, b, cfg, mesh, rhs_presplit)
     return _bmm_local(a, b, cfg.local(), rhs_presplit=rhs_presplit)

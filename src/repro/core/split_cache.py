@@ -172,8 +172,7 @@ def _cfg_key(cfg, k: int, dtype) -> Tuple:
 
 def _mesh_key() -> Tuple:
     try:
-        from repro.distributed import compat
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh.empty:
             return ()
         return tuple(sorted(dict(mesh.shape).items()))

@@ -93,12 +93,12 @@ def logical_to_pspec(axes: Sequence[Optional[str]],
 def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     """Annotate an activation with logical axes (no-op without mesh+rules).
 
-    Requires the mesh installed via ``repro.distributed.compat.set_mesh``
-    (a plain ``with mesh:`` does NOT set the abstract mesh on modern JAX
-    and this silently no-ops)."""
+    Requires the mesh installed via ``jax.set_mesh`` (a plain
+    ``with mesh:`` does NOT set the abstract mesh, and this silently
+    no-ops)."""
     if get_rules() is None:
         return x
-    from repro.distributed.compat import get_abstract_mesh
+    from jax.sharding import get_abstract_mesh
     mesh = get_abstract_mesh()
     if mesh.empty:
         return x

@@ -7,6 +7,8 @@
   * flash_attention  — fused online-softmax attention fwd (removes the
                        O(L^2) HBM score traffic identified in §Perf Cell A)
 
-Each has a pure-jnp oracle in ref.py; tests sweep shapes/dtypes with
-interpret=True (this container is CPU-only; TPU is the deploy target).
+Each has a pure-jnp oracle in ref.py.  The wrappers in ops.py compile
+the kernels through Mosaic when a program lowers for a TPU and interpret
+them when it lowers for the CPU, where the tests sweep shapes/dtypes;
+tests/test_tpu_compile.py compiles them for a described v5e.
 """

@@ -20,7 +20,13 @@ last axis fastest); it DOES depend on the batch axis, so each batch element
 gets a fresh accumulator (init fires at g == kn == 0 for every b).
 
 MXU alignment: bm/bp multiples of 128, bn a multiple of 128 (int8 lane
-tiling is (32, 128); 128 keeps both operand tiles aligned).  Callers pick
+tiling is (32, 128); 128 keeps both operand tiles aligned).
+
+Operands are int8, or bf16 for the sign-magnitude digits (values up to
+255, which the int8 MXU path cannot hold and Mosaic has no int16 matmul
+for): a bf16 block product accumulates in f32, exact while
+bn * 255^2 < 2^24 (``ops`` caps bn at 256), and converts to int32 before
+it joins the resident tile — the same integers as the int8 path.  Callers pick
 tiles from the planner's static-shape autotune table
 (``repro.core.plan.kernel_blocks`` via ``repro.kernels.ops.group_gemm``);
 the DEFAULT_* here are only the bare-kernel fallbacks.
@@ -49,9 +55,15 @@ def _group_gemm_kernel(a_ref, b_ref, c_ref):
     def _init():
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    c_ref[0] += jax.lax.dot_general(
-        a_ref[0, 0], b_ref[0, 0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    a, b = a_ref[0, 0], b_ref[0, 0]
+    dn = (((1,), (0,)), ((), ()))
+    if a.dtype == jnp.bfloat16:
+        prod = jax.lax.dot_general(a, b, dn,
+                                   preferred_element_type=jnp.float32)
+        c_ref[0] += prod.astype(jnp.int32)
+    else:
+        c_ref[0] += jax.lax.dot_general(a, b, dn,
+                                        preferred_element_type=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bp", "bn", "interpret"))
@@ -60,7 +72,8 @@ def group_gemm(a8: jax.Array, b8: jax.Array, *, bm: int = DEFAULT_BM,
                interpret: bool = False) -> jax.Array:
     """sum_g a8[..., g, :, :] @ b8[..., g, :, :] -> int32.
 
-    a8: (B, G, m, n) or (G, m, n) int8; b8: (B, G, n, p) or (G, n, p) int8.
+    a8: (B, G, m, n) or (G, m, n) int8; b8: (B, G, n, p) or (G, n, p) int8
+    (or both bf16 holding sign-magnitude digits, with bn <= 256).
     m/n/p must be multiples of the tiles (ops.py pads).  Caller guarantees
     G <= r (eq. 12) so INT32 cannot overflow — the sum is exact.  Returns
     (B, m, p) (or (m, p) for rank-3 inputs).

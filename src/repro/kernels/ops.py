@@ -2,23 +2,23 @@
 
 Handles: padding to tile multiples, row-scale preparation, slice-pair
 stacking for group GEMMs, batch flattening onto the kernels' leading grid
-axis, and the interpret-mode switch.  Block sizes come from the planner's
-static-shape autotune table (``repro.core.plan.kernel_blocks``), aligned
-per kernel with ``plan.tile``.
+axis, and the choice between compiled and interpreted kernels.  Block
+sizes come from the planner's static-shape autotune table
+(``repro.core.plan.kernel_blocks``), aligned per kernel with
+``plan.tile``.
 
-The ``INTERPRET`` module switch
--------------------------------
-``INTERPRET = True`` runs every Pallas kernel body through the interpreter:
-the grid is executed sequentially in Python and the body lowers to plain
-XLA ops on the host backend.  This is the *correctness reference path* —
-it is what the test suite exercises (this container has no TPU) and it is
-bit-identical to the compiled Mosaic kernel for the integer/exact-float
-arithmetic used here.  Flip to ``False`` on real TPUs to compile the
-kernels; nothing else in the call sites changes.  The switch is a module
-global (not a per-call flag) so that benchmarks, tests, and the engine all
-agree on one execution mode; override it *before* the first traced call —
-the wrappers are ``jit``'d with ``interpret`` as a static argument, so
-earlier traces are cached per mode.
+Compiled or interpreted
+-----------------------
+Every kernel call goes through :func:`_pallas`, which stages BOTH forms
+under ``lax.platform_dependent`` and lets lowering keep the one for the
+platform the program is compiled for: Mosaic on a TPU, the Pallas
+interpreter on the CPU (the test suite's correctness reference path —
+bit-identical to the compiled kernel for the integer/exact-float
+arithmetic used here).  Any other platform has no branch and fails to
+lower.  A kernel that Mosaic refuses raises at compile time; nothing
+retries it interpreted or falls back to the XLA path.  Specs that no
+compiled kernel can run (an f64 accumulator under ``:fused``) are refused
+earlier, by ``repro.core.ozimmu.parse_spec`` on a TPU backend.
 """
 from __future__ import annotations
 
@@ -37,8 +37,35 @@ from repro.kernels import scale_accum as _sa
 from repro.kernels import split_fused as _sf
 from repro.obs import tracing as _tracing
 
-# Flip to False when running on real TPUs.
-INTERPRET = True
+# int8 blocks tile as (32, 128) on the TPU: row tiles of int8 outputs
+# align to 32 rows, f32/int32 blocks to 8
+_INT8_ROWS = 32
+# sign-magnitude digits reach 255, so their slice products run on the MXU
+# in bf16 with f32 accumulation; a contraction block of at most 256
+# keeps every partial sum below 2^24 (256 * 255^2 < 2^24), i.e. exact
+_SM_MAX_BN = 256
+
+
+def _pallas(kernel: Callable, *args, **static):
+    """Run one Pallas kernel, compiled by Mosaic when the program lowers
+    for a TPU and interpreted when it lowers for the CPU (see the module
+    docstring).  The decision is made per lowering, so it follows the
+    device the call is placed on, not the process's default backend."""
+    def compiled(*operands):
+        # Mosaic lowers 32-bit grid/index arithmetic only, and under x64
+        # the BlockSpec index maps would trace to int64.  64-bit operands
+        # are traced as they are (x64 off would silently narrow them), so
+        # Mosaic refuses them when a TPU program is compiled; both
+        # branches are traced on every backend, so no error here.
+        if any(x.dtype.itemsize > 4 for x in operands):
+            return kernel(*operands, interpret=False, **static)
+        with jax.enable_x64(False):
+            return kernel(*operands, interpret=False, **static)
+
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=functools.partial(kernel, interpret=True, **static),
+        tpu=compiled)
 
 
 def _pad_to(x: jax.Array, mults: Sequence[int]) -> jax.Array:
@@ -60,7 +87,8 @@ def split_fused(a: jax.Array, k: int, beta: int, *, mode: str = "rn_const",
 
     Returns the same :class:`Split` contract as the pure-jnp splitters —
     bit-identical digits and scales, in ``a``'s own dtype (f64 inputs stay
-    f64 through the interpret path; on TPU use f32).  ``a`` is
+    f64 in the interpreter; Mosaic takes f32 only — ``parse_spec``
+    refuses f64 ``:fused`` specs on a TPU).  ``a`` is
     ``(*batch, m, n)``: splitting is row/column-local, so batch and row
     dims flatten together onto the kernel grid.  axis=1 (column scales,
     for B) transposes the trailing two axes in and out of the row kernel.
@@ -126,14 +154,14 @@ def split_fused(a: jax.Array, k: int, beta: int, *, mode: str = "rn_const",
     inv2 = (invgrid[:1, None] if const_grid
             else invgrid.reshape((rows, 1)))
     bm_pref, bn_pref, _ = plan.kernel_blocks(rows, n)
-    bm = plan.tile(rows, bm_pref, 8)
+    bm = plan.tile(rows, bm_pref, _INT8_ROWS)
     bn = plan.tile(n, bn_pref, 128)
     a_p = _pad_to(a2, (bm, bn))
     inv_p = inv2 if const_grid else _pad_to(inv2, (bm, 1))
     with _tracing.phase_scope("kernel/split_fused"):
-        digits = _sf.split_fused(a_p, inv_p, k=k, beta=beta, mode=kmode,
-                                 bm=bm, bn=bn, const_grid=const_grid,
-                                 interpret=INTERPRET)[:, :rows, :n]
+        digits = _pallas(_sf.split_fused, a_p, inv_p, k=k, beta=beta,
+                         mode=kmode, bm=bm, bn=bn,
+                         const_grid=const_grid)[:, :rows, :n]
     digits = digits.reshape((k,) + batch + (m, n))
     return Split(digits, _geo_scales(base, beta, k), base, beta, 0,
                  gbase=gbase, signmag=(mode == "sm"))
@@ -151,10 +179,15 @@ def group_gemm(sa: Split, sb: Split, pairs: Sequence[Tuple[int, int]]
     """
     idx_a = [s - 1 for s, _ in pairs]
     idx_b = [t - 1 for _, t in pairs]
-    # sign-magnitude splits widen to int16 values before the gather (the
-    # Pallas MAC body is dtype-generic; int32 accumulation is unchanged)
+    # sign-magnitude digits decode to [-128, 255] before the gather; the
+    # MXU has no int16 path, so they enter the kernel as bf16 (exact for
+    # these integers) and each block product accumulates exactly in f32
+    # (see _SM_MAX_BN) before joining the int32 tile
+    signmag = sa.signmag or sb.signmag
     da = sm_decode(sa.digits) if sa.signmag else sa.digits
     db = sm_decode(sb.digits) if sb.signmag else sb.digits
+    if signmag:
+        da, db = da.astype(jnp.bfloat16), db.astype(jnp.bfloat16)
     a8 = da[jnp.asarray(idx_a)]             # (G, *batch, m, n)
     b8 = db[jnp.asarray(idx_b)]
     G = a8.shape[0]
@@ -165,13 +198,13 @@ def group_gemm(sa: Split, sb: Split, pairs: Sequence[Tuple[int, int]]
     b8 = jnp.moveaxis(b8, 0, -3).reshape((-1, G, n, p))
     bm_pref, bn_pref, bp_pref = plan.kernel_blocks(m, n, p)
     bm = plan.tile(m, bm_pref, 128)
-    bn = plan.tile(n, bn_pref, 128)
+    bn = plan.tile(n, min(bn_pref, _SM_MAX_BN) if signmag else bn_pref,
+                   128)
     bp = plan.tile(p, bp_pref, 128)
     a8 = _pad_to(a8, (1, 1, bm, bn))
     b8 = _pad_to(b8, (1, 1, bn, bp))
     with _tracing.phase_scope("kernel/group_gemm"):
-        out = _gg.group_gemm(a8, b8, bm=bm, bp=bp, bn=bn,
-                             interpret=INTERPRET)
+        out = _pallas(_gg.group_gemm, a8, b8, bm=bm, bp=bp, bn=bn)
     return out[:, :m, :p].reshape(batch + (m, p))
 
 
@@ -203,8 +236,8 @@ def scale_accum(p32: jax.Array, srow: jax.Array, scol: jax.Array,
     p32_p, srow_p, scol_p, (hi_p, lo_p), bm, bp, unpad = \
         _epilogue_operands(p32, srow, scol, c_hi, c_lo)
     with _tracing.phase_scope("kernel/scale_accum"):
-        hi, lo = _sa.scale_accum(p32_p, srow_p, scol_p, hi_p, lo_p, bm=bm,
-                                 bp=bp, interpret=INTERPRET)
+        hi, lo = _pallas(_sa.scale_accum, p32_p, srow_p, scol_p, hi_p,
+                         lo_p, bm=bm, bp=bp)
     return unpad(hi), unpad(lo)
 
 
@@ -215,8 +248,8 @@ def scale_accum_plain(p32: jax.Array, srow: jax.Array, scol: jax.Array,
     p32_p, srow_p, scol_p, (c_p,), bm, bp, unpad = \
         _epilogue_operands(p32, srow, scol, c)
     with _tracing.phase_scope("kernel/scale_accum"):
-        out = _sa.scale_accum_plain(p32_p, srow_p, scol_p, c_p, bm=bm,
-                                    bp=bp, interpret=INTERPRET)
+        out = _pallas(_sa.scale_accum_plain, p32_p, srow_p, scol_p, c_p,
+                      bm=bm, bp=bp)
     return unpad(out)
 
 
@@ -260,8 +293,8 @@ def oz2_scale_accum(word: jax.Array, s: jax.Array, c_hi: jax.Array,
     word_p, s_p, (hi_p, lo_p), bm, bp, unpad = \
         _oz2_epilogue_operands(word, s, c_hi, c_lo)
     with _tracing.phase_scope("kernel/scale_accum"):
-        hi, lo = _sa.scale_accum_const(word_p, s_p, hi_p, lo_p, bm=bm,
-                                       bp=bp, interpret=INTERPRET)
+        hi, lo = _pallas(_sa.scale_accum_const, word_p, s_p, hi_p, lo_p,
+                         bm=bm, bp=bp)
     return unpad(hi), unpad(lo)
 
 
@@ -270,8 +303,8 @@ def oz2_scale_accum_plain(word: jax.Array, s: jax.Array, c: jax.Array):
     int64 ladder word in f64/x64 mode)."""
     word_p, s_p, (c_p,), bm, bp, unpad = _oz2_epilogue_operands(word, s, c)
     with _tracing.phase_scope("kernel/scale_accum"):
-        out = _sa.scale_accum_const_plain(word_p, s_p, c_p, bm=bm, bp=bp,
-                                          interpret=INTERPRET)
+        out = _pallas(_sa.scale_accum_const_plain, word_p, s_p, c_p,
+                      bm=bm, bp=bp)
     return unpad(out)
 
 
@@ -301,8 +334,7 @@ def oz2_unscale(x: jax.Array, ra: jax.Array, rb: jax.Array) -> jax.Array:
     ra_p = _pad_to(ra.reshape((B, m, 1)).astype(x.dtype), (1, bm, 1))
     rb_p = _pad_to(rb.reshape((B, 1, p)).astype(x.dtype), (1, 1, bp))
     with _tracing.phase_scope("kernel/unscale"):
-        out = _sa.unscale(x_p, ra_p, rb_p, bm=bm, bp=bp,
-                          interpret=INTERPRET)
+        out = _pallas(_sa.unscale, x_p, ra_p, rb_p, bm=bm, bp=bp)
     return out[:, :m, :p].reshape(batch + (m, p))
 
 
@@ -341,9 +373,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qt = qt.transpose(0, 2, 1, 3).reshape(B * H, Lq_p, D)
     kt = kt.transpose(0, 2, 1, 3).reshape(B * KV, Lk_p, D)
     vt = vt.transpose(0, 2, 1, 3).reshape(B * KV, Lk_p, Dv)
-    o, _ = _fa.flash_attention_fwd(qt, kt, vt, group=group, causal=causal,
-                                   window=window, qc=qc, kc=kc,
-                                   q_offset=q_offset, lk=Lk,
-                                   interpret=INTERPRET)
+    o, _ = _pallas(_fa.flash_attention_fwd, qt, kt, vt, group=group,
+                   causal=causal, window=window, qc=qc, kc=kc,
+                   q_offset=q_offset, lk=Lk)
     o = o.reshape(B, H, Lq_p, Dv).transpose(0, 2, 1, 3)
     return o[:, :Lq]

@@ -1,10 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Everything below may now import jax.
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective analysis.
+
+Run as a script: ``main`` adds ``--xla_force_host_platform_device_count=512``
+to ``XLA_FLAGS`` before JAX initializes its backends (importing this
+module changes nothing).
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch deepseek_7b \
         --shape train_4k --mesh single            # one cell
@@ -17,6 +16,7 @@ Each cell writes ``<out>/<arch>__<shape>__<mesh>.json`` with:
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -28,7 +28,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs, optim
 from repro.configs import SHAPES
-from repro.distributed import compat
 from repro.distributed.sharding import spec_tree, use_rules
 from repro.launch import hlo_cost, roofline, steps
 from repro.launch.mesh import make_production_mesh, mesh_rules
@@ -88,7 +87,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, engine: str = "bf16",
     model = api.get_model(cfg)
     t0 = time.time()
 
-    with compat.set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         pshapes, axes = steps.params_shapes(cfg)
         n_params = roofline.count_params(pshapes)
         p_spec = spec_tree(axes, rules)
@@ -246,7 +245,17 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     return record
 
 
+def _force_host_devices(n: int = 512) -> None:
+    """Give the CPU backend ``n`` devices for the production meshes; must
+    run before JAX initializes a backend (it keeps other XLA flags)."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if flag not in flags.split():
+        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+
+
 def main(argv=None):
+    _force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

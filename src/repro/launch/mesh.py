@@ -10,20 +10,29 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the partitioner places every
+    intermediate, and sharding annotations are hints, not assertions
+    (JAX 0.9 defaults ``make_mesh`` to Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None):
     """Small mesh over however many (host) devices tests forced."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: Optional[str]):
@@ -59,7 +68,7 @@ def parse_mesh_spec(spec: Optional[str]):
     if total > ndev:
         raise ValueError(f"mesh spec {spec!r} needs {total} devices, "
                          f"only {ndev} available")
-    return jax.make_mesh(tuple(sizes), tuple(axes))
+    return _auto_mesh(sizes, axes)
 
 
 def mesh_rules(mesh, arch: Optional[str] = None):

@@ -4,6 +4,10 @@
         --requests 8 --slots 4 --prompt-len 32 --gen 32 \
         --engine ozimmu_h-8:df32 --page-block 16
 
+Reduced widths (``--smoke``, the default) keep CPU runs short; ``--full``
+serves the published widths, and ``--n-layers`` cuts depth to fit one
+chip (weights are random, from a fixed seed).
+
 The heavy lifting lives in :mod:`repro.serving` (docs/serving.md): a
 slot-based continuous batcher with bucketed batched prefill (mixed-length
 prompts share one compiled call), an optional block-paged KV pool
@@ -25,8 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.distributed import compat
 from repro.distributed.sharding import use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_rules, parse_mesh_spec
 from repro.models import api
 from repro.obs import export as obs_export
@@ -79,6 +83,12 @@ def dump_metrics(path: str, runtime: ServingRuntime,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2_1_8b")
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="reduced same-family widths (default)")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the published widths of --arch")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="override the layer count (depth is the only cut)")
     ap.add_argument("--slots", "--batch", dest="slots", type=int, default=4,
                     help="decode slots (compiled batch dimension)")
     ap.add_argument("--requests", type=int, default=None,
@@ -124,9 +134,12 @@ def main(argv=None):
 
     mesh = parse_mesh_spec(args.mesh)
     rules = mesh_rules(mesh, args.arch) if mesh is not None else None
-    mesh_ctx = (compat.set_mesh(mesh) if mesh is not None
+    mesh_ctx = (jax.set_mesh(mesh) if mesh is not None
                 else contextlib.nullcontext())
-    cfg = configs.get_config(args.arch, smoke=True, engine_spec=args.engine)
+    overrides = {"engine_spec": args.engine}
+    if args.n_layers is not None:
+        overrides["n_layers"] = args.n_layers
+    cfg = configs.get_config(args.arch, smoke=args.smoke, **overrides)
     oz_cfg = cfg.engine.ozimmu_config
     if oz_cfg is not None:
         from repro.core import plan
@@ -198,8 +211,11 @@ def main(argv=None):
               f"{obs['highprec_adds']:.0f} high-precision adds")
     print("[serve] sample continuation:",
           outs[0][-args.gen:][:16].tolist())
-    return s
+    return {"summary": s,
+            "generated": [list(map(int, r.generated)) for r in reqs],
+            "decode_observed": runtime.decode_observed}
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -3,13 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch internlm2_1_8b \
         --smoke --steps 200 --batch 8 --seq 256 --ckpt-dir /tmp/ckpt
 
-Production posture on a real cluster: same entry point per host
-(jax.distributed.initialize from the plugin environment), production mesh
-from launch.mesh, host-sharded pipeline, async checkpointing, and
-restart-resume — on restart the driver finds the latest checkpoint, restores
-(resharding onto the current mesh if it changed — elastic), and continues
-from the saved step.  On this CPU container it runs the reduced configs
-(--smoke) for the examples/tests.
+One process drives the devices it sees: the ``--mesh`` spec lays them out
+(launch.mesh), the pipeline shards the host batch, checkpoints save
+asynchronously, and on restart the trainer finds the latest checkpoint,
+restores it (resharding onto the current mesh if it changed — elastic)
+and continues from the saved step.  ``--smoke`` (the default) runs the
+reduced configs used by the examples and tests; ``--full`` the published
+widths.
 """
 from __future__ import annotations
 
@@ -24,9 +24,9 @@ import numpy as np
 from repro import configs, optim
 from repro.checkpoint import Checkpointer
 from repro.data import DataConfig, Pipeline
-from repro.distributed import compat
 from repro.distributed.sharding import use_rules
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_rules, parse_mesh_spec
 from repro.models import api
 
@@ -61,7 +61,7 @@ def train(arch: str, *, smoke: bool = True, n_steps: int = 100,
     ckpt = Checkpointer(ckpt_dir) if ckpt_dir else None
 
     import contextlib
-    mesh_ctx = (compat.set_mesh(mesh) if mesh is not None
+    mesh_ctx = (jax.set_mesh(mesh) if mesh is not None
                 else contextlib.nullcontext())
     with mesh_ctx, use_rules(rules):
         rng = jax.random.PRNGKey(seed)
@@ -143,4 +143,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,9 +25,13 @@ def _edot(engine, lhs, rhs, dimension_numbers, out_dtype=None):
     for ``engine=None`` library use — this stays a plain lax.dot_general,
     bit-identical to the einsums it replaced: attention keeps its own
     mixed-precision discipline (f32 scores/probabilities feeding the online
-    softmax and its backward), which an engine-dtype cast would truncate."""
+    softmax and its backward), which an engine-dtype cast would truncate.
+    The ``f32``/``f64`` reference specs ask for HIGHEST precision, which a
+    TPU would otherwise drop to one bf16 pass."""
     if engine is None or not engine.is_ozimmu:
+        precision = None if engine is None else engine.native_precision
         return lax.dot_general(lhs, rhs, dimension_numbers,
+                               precision=precision,
                                preferred_element_type=out_dtype)
     return engine.dot_general(lhs, rhs, dimension_numbers,
                               out_dtype=out_dtype)

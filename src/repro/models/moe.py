@@ -170,19 +170,11 @@ def moe_ffn_a2a(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     per-expert blocks with one all-to-all (bytes ~ tokens*K*d, ~400x less),
     runs its resident experts, and all-to-alls back.
     """
-    from repro.distributed import compat
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     if not dp_axes or "data" not in mesh.axis_names or \
             cfg.n_experts % mesh.shape["data"] != 0:
         return moe_ffn(p, cfg, x)  # fallback: no mesh / indivisible experts
-    if getattr(jax, "shard_map", None) is None:
-        # 0.4.x runtimes: lax.all_to_all under a partial-manual shard_map
-        # (model axis auto) hard-crashes the XLA SPMD partitioner
-        # (IsManualSubgroup check).  The scatter path is numerically
-        # identical (tests/test_distributed.py asserts it on modern JAX),
-        # just without the a2a bandwidth saving.
-        return moe_ffn(p, cfg, x)
 
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.topk
@@ -229,7 +221,7 @@ def moe_ffn_a2a(p, cfg: ModelConfig, x: jax.Array) -> jax.Array:
                               xt.dtype)
 
     xt = x.reshape(B * S, d)
-    out = compat.shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp_axes, None), P(), P("data", None, None),

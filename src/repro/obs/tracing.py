@@ -15,14 +15,14 @@ Two scope flavors, both no-ops when the obs layer is disabled:
 
 Run-level capture: :func:`profile` brackets a whole run with
 ``jax.profiler.start_trace/stop_trace`` (the ``--profile-dir`` flag on
-serve/train).  Failures to start the profiler degrade to a warning, not
-a crash — observability must never take the workload down.
+serve/train).  A trace that was asked for and cannot start or stop
+raises: a run without the trace it was launched to capture is a failed
+run, not a quiet one.  Only :func:`host_scope` degrades silently.
 """
 
 from __future__ import annotations
 
 import contextlib
-import sys
 from typing import Optional
 
 import jax
@@ -60,23 +60,13 @@ def host_scope(name: str):
 @contextlib.contextmanager
 def profile(trace_dir: Optional[str]):
     """Bracket a run with ``jax.profiler.start_trace/stop_trace`` when
-    ``trace_dir`` is set; plain passthrough when None."""
+    ``trace_dir`` is set; plain passthrough when None.  Errors from
+    either call propagate."""
     if not trace_dir:
         yield
         return
-    started = False
-    try:
-        jax.profiler.start_trace(trace_dir)
-        started = True
-    except Exception as e:  # missing profiler deps / double start
-        print(f"[obs] profiler trace unavailable ({e}); continuing "
-              f"without", file=sys.stderr)
+    jax.profiler.start_trace(trace_dir)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:
-                print(f"[obs] profiler stop_trace failed ({e})",
-                      file=sys.stderr)
+        jax.profiler.stop_trace()

@@ -55,7 +55,7 @@ def zero_axes(axes_tree, params, zero_divisor: int):
     The effective divisor is derived from the live mesh + the 'zero' rule
     when available (it may span several mesh axes, e.g. (pod, data));
     ``zero_divisor`` is the fallback when no mesh is installed."""
-    from repro.distributed.compat import get_abstract_mesh
+    from jax.sharding import get_abstract_mesh
     rules = get_rules() or {}
     mesh = get_abstract_mesh()
     if rules.get("zero") and not mesh.empty:

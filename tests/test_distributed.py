@@ -38,7 +38,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.launch import steps as S
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.distributed.sharding import use_rules
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
 
         arch = "internlm2_1_8b"
         cfg = configs.get_config(arch, smoke=True)
@@ -82,7 +82,7 @@ def test_sharded_decode_matches_forward():
         from repro.models import api
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.distributed.sharding import use_rules
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
 
         arch = "recurrentgemma_9b"   # hybrid: ring buffers + LRU state
         cfg = configs.get_config(arch, smoke=True)
@@ -155,7 +155,7 @@ def test_mini_dryrun_lower_compile_families():
         from repro.launch import steps as S
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.distributed.sharding import use_rules, spec_tree
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.models import api
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -199,7 +199,7 @@ def test_moe_a2a_dispatch_matches_scatter():
         from repro.models import moe
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.distributed.sharding import use_rules
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
 
         cfg = configs.get_config("deepseek_moe_16b", smoke=True,
                                  capacity_factor=4.0)
@@ -235,7 +235,7 @@ def test_elastic_restore_across_meshes():
         from repro.launch import steps as S
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.distributed.sharding import use_rules, spec_tree
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         import tempfile
 
         arch = "internlm2_1_8b"
@@ -283,7 +283,7 @@ def test_ozimmu_sharded_bitwise_all_variants():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(0)
@@ -317,7 +317,7 @@ def test_ozimmu_sharded_bitwise_x64():
         import numpy as np, jax, jax.numpy as jnp
         assert jax.config.jax_enable_x64
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(3)
@@ -345,7 +345,7 @@ def test_ozimmu_batch_sharded_matches_single_device():
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(1)
@@ -374,7 +374,7 @@ def test_ozimmu_sharded_vjp_bitwise():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(2)
@@ -405,7 +405,7 @@ def test_ozimmu_sharded_fused_pipeline_bitwise():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(0)
@@ -447,7 +447,7 @@ def test_oz2_sharded_bitwise_both_modes():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(5)
@@ -486,7 +486,7 @@ def test_oz2_fast2_sharded_int32_bitwise():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(11)
@@ -524,7 +524,7 @@ def test_sm_auto_sharded_int32_bitwise():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu, split_cache
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(17)
@@ -570,7 +570,7 @@ def test_presplit_sharded_bitwise_all_variants():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu, split_cache
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(9)
@@ -609,7 +609,6 @@ def test_serving_runtime_mesh_smoke():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro import configs
-        from repro.distributed import compat
         from repro.distributed.sharding import use_rules
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.models import api
@@ -619,7 +618,7 @@ def test_serving_runtime_mesh_smoke():
         mesh = make_test_mesh(data=2, model=4)
         cfg = configs.get_config(arch, smoke=True,
                                  engine_spec="ozimmu_h-4:df32@model")
-        with compat.set_mesh(mesh), use_rules(mesh_rules(mesh, arch)):
+        with jax.set_mesh(mesh), use_rules(mesh_rules(mesh, arch)):
             model = api.get_model(cfg)
             params, _ = model.init(jax.random.PRNGKey(0), cfg)
             rt = ServingRuntime(cfg, params, slots=2, max_len=32)
@@ -644,7 +643,6 @@ def test_serving_prefix_chunked_paged_mesh_bitwise():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro import configs
-        from repro.distributed import compat
         from repro.distributed.sharding import use_rules
         from repro.launch.mesh import make_test_mesh, mesh_rules
         from repro.models import api
@@ -654,7 +652,7 @@ def test_serving_prefix_chunked_paged_mesh_bitwise():
         mesh = make_test_mesh(data=2, model=4)
         cfg = configs.get_config(arch, smoke=True,
                                  engine_spec="ozimmu_h-4:df32@model")
-        with compat.set_mesh(mesh), use_rules(mesh_rules(mesh, arch)):
+        with jax.set_mesh(mesh), use_rules(mesh_rules(mesh, arch)):
             model = api.get_model(cfg)
             params, _ = model.init(jax.random.PRNGKey(0), cfg)
             rng = np.random.default_rng(0)
@@ -688,7 +686,7 @@ def test_psum_df32_error_free_vs_plain_f32():
         from jax.sharding import PartitionSpec as P
         from repro.core.accumulate import DF32
         from repro.distributed import collectives
-        from repro.distributed.compat import set_mesh, shard_map
+        from jax import set_mesh, shard_map
         from repro.launch.mesh import make_test_mesh
 
         mesh = make_test_mesh(data=8, model=1)
@@ -722,7 +720,7 @@ def test_ozimmu_sharded_df32_reduce_accuracy():
     run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(4)

@@ -203,3 +203,28 @@ def test_dnum_validation():
         ozimmu_dot_general(a, b, (((2,), (1,), (0,)), ((0,), (0,))))
     with pytest.raises(ValueError):
         ozimmu_matmul(a, b)
+
+
+def _dot_precisions(fn, *args):
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return [e.params["precision"] for e in jaxpr.eqns
+            if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("spec", ["f32", "f64"])
+def test_native_reference_engines_ask_highest_precision(spec):
+    """The f32/f64 reference engines carry Precision.HIGHEST in their dots
+    (a TPU would otherwise run a one-pass bf16 product); bf16 and the
+    engine-less attention dots keep the backend default."""
+    from repro.models import layers
+    hi = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    x = jnp.ones((2, 8), jnp.float32)
+    w = jnp.ones((8, 4), jnp.float32)
+    dn = (((1,), (0,)), ((), ()))
+    eng = make_engine(spec)
+    assert _dot_precisions(eng, x, w) == [hi]
+    assert _dot_precisions(
+        lambda a, b: layers._edot(eng, a, b, dn), x, w) == [hi]
+    assert _dot_precisions(make_engine("bf16"), x, w) == [None]
+    assert _dot_precisions(
+        lambda a, b: layers._edot(None, a, b, dn), x, w) == [None]

@@ -219,6 +219,18 @@ def test_observed_counts_batched_and_presplit(fresh_registry):
     assert d.total("emulation.split_bytes") == 4 * a.size
 
 
+def test_mesh_label_none_when_sharded_path_falls_back(fresh_registry,
+                                                      operands):
+    """A mesh-native spec without an installed mesh runs the local path,
+    and its counters say so (mesh="none")."""
+    a, b = operands
+    ozimmu.ozimmu_dot_general(a, b, DN,
+                              ozimmu.parse_spec("ozimmu_h-4:df32@model"))
+    snap = fresh_registry.snapshot()
+    assert snap.total("emulation.int8_gemms", mesh="none") > 0
+    assert snap.total("emulation.int8_gemms", mesh="model") == 0
+
+
 def test_trace_time_recording_once_per_compile(fresh_registry, operands):
     """Counters record at trace time: a jitted call records once at
     compile, and compiled replays add nothing (each replay executes the
@@ -276,7 +288,7 @@ def test_bitwise_identity_mesh_int32_obs_on_off():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
         from repro.obs import registry
-        from repro.distributed.compat import set_mesh
+        from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
 
         rng = np.random.default_rng(11)
@@ -401,3 +413,52 @@ def test_json_document_totals_and_ledger(fresh_registry, operands):
     assert doc["totals"]["serving.tokens_generated"] == 12
     assert doc["plan_ledger"]["decisions"] >= 1
     assert "serving_summary" in doc
+
+
+# ---------------------------------------------------------------------------
+# run-level profiler capture
+# ---------------------------------------------------------------------------
+
+def test_profile_raises_when_trace_cannot_start(monkeypatch, tmp_path):
+    """A trace that was asked for and cannot start is an error, not a
+    warning; without a directory ``profile`` is a passthrough."""
+    from repro.obs import tracing
+
+    def refuse(*a, **k):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with tracing.profile(str(tmp_path)):
+            pass
+    ran = []
+    with tracing.profile(None):
+        ran.append(1)
+    assert ran == [1]
+
+
+def test_profile_raises_when_trace_cannot_stop(monkeypatch, tmp_path):
+    from repro.obs import tracing
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: started.append(d))
+
+    def refuse():
+        raise RuntimeError("stop failed")
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", refuse)
+    with pytest.raises(RuntimeError, match="stop failed"):
+        with tracing.profile(str(tmp_path)):
+            pass
+    assert started == [str(tmp_path)]
+
+
+def test_host_scope_degrades_without_profiler(monkeypatch):
+    from repro.obs import tracing
+
+    def refuse(name):
+        raise RuntimeError("no profiler backend")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    with tracing.host_scope("split"):
+        pass

@@ -198,3 +198,25 @@ def test_train_restart_equivalence(tmp_path):
                            global_batch=2, seq_len=32, ckpt_dir=ck,
                            ckpt_every=10, log_every=0, seed=3)[1]
     np.testing.assert_allclose(losses_resumed, losses_ref[2:], rtol=1e-5)
+
+
+def test_compile_cache_directory(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR wins and
+    no other directory is set; otherwise one fixed directory inside the
+    checkout.  (jax.config.update is recorded, not applied: the tests
+    never turn the cache on.)"""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/the/user")
+    assert compile_cache.enable_compile_cache() == "/placed/by/the/user"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    where = compile_cache.enable_compile_cache()
+    checkout = Path(__file__).resolve().parents[1]
+    assert where == str(checkout / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", where)]
+    assert compile_cache.enable_compile_cache() == where
