@@ -407,6 +407,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    from repro.launch.compile_cache import enable_compile_cache
-    enable_compile_cache()
+    from repro.launch.entry import prepare_entry_point
+    prepare_entry_point()
     main()

@@ -198,7 +198,8 @@ def variant_name(cfg: "OzimmuConfig") -> str:
 
 
 def _record_emulation(cfg: "OzimmuConfig", a, p: int,
-                      presplit: bool, sharded: bool) -> None:
+                      presplit: bool, sharded: bool,
+                      spec_cfg: "OzimmuConfig") -> None:
     """Mirror one resolved contraction into the metrics registry.
 
     Called from ``_bmm_impl`` after the config is fully canonical (fast2
@@ -214,12 +215,19 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     observed == planned a testable invariant (tests/test_obs.py).
     Shapes/dtypes only — never touches values, so tracers stay clean and
     outputs are bitwise-identical with obs on or off.
+
+    ``emulation.int8_gemms_planned`` prices the same contraction with the
+    planner's static (shape-only) plan under ``spec_cfg``, the config
+    before k was fixed: in a jitted program, where auto-k resolves
+    statically and a frozen split carries the k it froze, it must equal
+    ``emulation.int8_gemms``.
     """
     from repro.core import plan as _plan
     m, n = a.shape[-2], a.shape[-1]
     # canonical operands share batch dims; b is (*batch, n, p)
     batch = int(np.prod(a.shape[:-2], dtype=np.int64)) if a.ndim > 2 else 1
     pl = _plan.plan_contraction(cfg, m, n, p)
+    static = _plan.plan_contraction(spec_cfg, m, n, p, a=a, _record=False)
     labels = dict(
         variant=variant_name(cfg), k=cfg.k,
         path=("fused" if cfg.use_pallas == "fused"
@@ -229,6 +237,8 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     reg = _obs.get_registry()
     reg.inc("emulation.calls", 1, **labels)
     reg.inc("emulation.int8_gemms", batch * pl.int8_gemms, **labels)
+    reg.inc("emulation.int8_gemms_planned", batch * static.int8_gemms,
+            **labels)
     reg.inc("emulation.highprec_adds", batch * pl.highprec_adds, **labels)
     itemsize = np.dtype(a.dtype).itemsize
     split_elems = batch * m * n            # A is always split in-call;
@@ -606,6 +616,7 @@ def _bmm_impl(a: jax.Array, b: jax.Array, cfg: OzimmuConfig,
         # explicitly (the documented footgun — see docs/engine.md) instead
         # of emitting one truncation warning per accumulation step
         cfg = cfg.with_(accum_dtype="f32")
+    spec_cfg = cfg
     if cfg.auto_k:
         if rhs_presplit is not None:
             # the cache resolved auto-k at freeze time with the static
@@ -627,7 +638,7 @@ def _bmm_impl(a: jax.Array, b: jax.Array, cfg: OzimmuConfig,
     mesh = _mesh_for(cfg, a.shape[-1])
     if _obs.enabled():
         _record_emulation(cfg, a, b.shape[-1], rhs_presplit is not None,
-                          sharded=mesh is not None)
+                          sharded=mesh is not None, spec_cfg=spec_cfg)
     if mesh is not None:
         return _bmm_sharded(a, b, cfg, mesh, rhs_presplit)
     return _bmm_local(a, b, cfg.local(), rhs_presplit=rhs_presplit)

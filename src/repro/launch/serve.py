@@ -30,7 +30,7 @@ import numpy as np
 
 from repro import configs
 from repro.distributed.sharding import use_rules
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.entry import prepare_entry_point
 from repro.launch.mesh import mesh_rules, parse_mesh_spec
 from repro.models import api
 from repro.obs import export as obs_export
@@ -207,15 +207,20 @@ def main(argv=None):
         print(f"[serve] observed per decode step: "
               f"{obs['contractions']:.0f} contractions, "
               f"{obs['int8_gemms']:.0f} int8 GEMMs "
-              f"({obs['int8_gemms_presplit']:.0f} on presplit weights), "
+              f"({obs['int8_gemms_presplit']:.0f} on presplit weights; "
+              f"planned {obs['int8_gemms_planned']:.0f}), "
               f"{obs['highprec_adds']:.0f} high-precision adds")
+    if runtime.nonfinite_logit_calls:
+        print(f"[serve] WARNING: {runtime.nonfinite_logit_calls} prefill/"
+              f"decode calls produced non-finite logits")
     print("[serve] sample continuation:",
           outs[0][-args.gen:][:16].tolist())
     return {"summary": s,
             "generated": [list(map(int, r.generated)) for r in reqs],
-            "decode_observed": runtime.decode_observed}
+            "decode_observed": runtime.decode_observed,
+            "nonfinite_logit_calls": runtime.nonfinite_logit_calls}
 
 
 if __name__ == "__main__":
-    enable_compile_cache()
+    prepare_entry_point()
     main()

@@ -26,7 +26,7 @@ from repro.checkpoint import Checkpointer
 from repro.data import DataConfig, Pipeline
 from repro.distributed.sharding import use_rules
 from repro.launch import steps as S
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.entry import prepare_entry_point
 from repro.launch.mesh import mesh_rules, parse_mesh_spec
 from repro.models import api
 
@@ -143,5 +143,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    enable_compile_cache()
+    prepare_entry_point()
     main()

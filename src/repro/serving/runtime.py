@@ -132,6 +132,9 @@ class ServingRuntime:
         # same contractions every execution).  None until a step traced
         # with obs enabled; persists across reset_metrics.
         self.decode_observed: Optional[Dict[str, float]] = None
+        # prefill/decode calls whose logits of a live slot held a NaN or
+        # an inf (greedy argmax would pick a token from them silently)
+        self.nonfinite_logit_calls = 0
 
         batch_ctx = None if ctx is None else jnp.concatenate(
             [ctx] * slots, axis=0)
@@ -194,12 +197,19 @@ class ServingRuntime:
     # jitted step bodies
     # ------------------------------------------------------------------
 
-    def _step(self, params, cache, toks, cur):
+    def _greedy(self, logits, live):
+        """Greedy tokens of (slots, padded vocab) logits, and whether
+        every live slot's logits are finite (idle slots compute on
+        garbage and are not looked at)."""
+        logits = logits[:, :self.cfg.vocab]
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, jnp.all(jnp.isfinite(logits) | ~live[:, None])
+
+    def _step(self, params, cache, toks, cur, active):
         logits, new_cache = self.model.decode_step(params, self.cfg, cache,
                                                    toks, cur)
-        nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab],
-                         axis=-1).astype(jnp.int32)
-        return nxt, new_cache
+        nxt, finite = self._greedy(logits[:, -1], active)
+        return nxt, finite, new_cache
 
     def _decode_impl(self, params, cache, toks, cur, active):
         # no per-slot select by default: inactive slots carry cur == 0,
@@ -209,19 +219,21 @@ class ServingRuntime:
         # reuse.  The exception is chunked state families (see
         # _decode_select) — a mid-prefill slot's recurrent state is live
         # and must not integrate a decode step.
-        nxt, new_cache = self._step(params, cache, toks, cur)
+        nxt, finite, new_cache = self._step(params, cache, toks, cur,
+                                            active)
         if self._decode_select:
             new_cache = self.ops.select_slots(new_cache, cache, active)
-        return nxt, new_cache
+        return nxt, finite, new_cache
 
     def _decode_paged_impl(self, params, pool, state, tables, toks, cur,
                            active):
         paged = self.paged
         cache = paged._gather(pool, tables, state)
-        nxt, new_cache = self._step(params, cache, toks, cur)
+        nxt, finite, new_cache = self._step(params, cache, toks, cur,
+                                            active)
         pool, state = paged._scatter_rows(pool, tables, new_cache, cur,
                                           active, state)
-        return nxt, pool, state
+        return nxt, finite, pool, state
 
     def _chunk_body(self, params, cache, toks, start, base, newmask):
         """scan of the decode step over the bucket; each participating
@@ -236,9 +248,8 @@ class ServingRuntime:
             return c, logits[:, -1]
 
         cache, logits = jax.lax.scan(body, cache, jnp.arange(Lb))
-        nxt = jnp.argmax(logits[-1][:, :self.cfg.vocab],
-                         axis=-1).astype(jnp.int32)
-        return nxt, cache
+        nxt, finite = self._greedy(logits[-1], newmask)
+        return nxt, finite, cache
 
     # per-instance memoization by bucket length (NOT functools.lru_cache
     # on the bound method — a class-level cache keyed on self would pin
@@ -247,9 +258,10 @@ class ServingRuntime:
         fn = self._prefill_fns.get(Lb)
         if fn is None:
             def impl(params, cache, toks, start, base, newmask):
-                nxt, after = self._chunk_body(params, cache, toks,
-                                              start, base, newmask)
-                return nxt, self.ops.select_slots(after, cache, newmask)
+                nxt, finite, after = self._chunk_body(
+                    params, cache, toks, start, base, newmask)
+                return nxt, finite, self.ops.select_slots(after, cache,
+                                                          newmask)
             fn = self._prefill_fns[Lb] = jax.jit(impl)
         return fn
 
@@ -259,9 +271,10 @@ class ServingRuntime:
             def impl(params, pool, state, tables, toks, start, base,
                      newmask):
                 cache0 = self.paged._gather(pool, tables, state)
-                nxt, after = self._chunk_body(params, cache0, toks,
-                                              start, base, newmask)
-                return nxt, self.ops.select_slots(after, cache0, newmask)
+                nxt, finite, after = self._chunk_body(
+                    params, cache0, toks, start, base, newmask)
+                return nxt, finite, self.ops.select_slots(after, cache0,
+                                                          newmask)
             fn = self._prefill_fns[("paged", Lb)] = jax.jit(impl)
         return fn
 
@@ -419,11 +432,13 @@ class ServingRuntime:
             t0 = self._now()
             if self.paged is not None:
                 fn = self._prefill_paged_fn(Lb)
-                nxt, after = fn(self.params, self.paged.pool,
-                                self.paged.state,
-                                self.paged.device_tables(),
-                                jnp.asarray(toks), jnp.asarray(start),
-                                jnp.asarray(base), jnp.asarray(newmask))
+                nxt, finite, after = fn(self.params, self.paged.pool,
+                                        self.paged.state,
+                                        self.paged.device_tables(),
+                                        jnp.asarray(toks),
+                                        jnp.asarray(start),
+                                        jnp.asarray(base),
+                                        jnp.asarray(newmask))
                 for slot, req, clen in ready:
                     done = self.sched.slots[slot].prefilled
                     length, span_start = self._span_args(done, clen)
@@ -432,11 +447,12 @@ class ServingRuntime:
                 self.paged.set_state_from(after)
             else:
                 fn = self._prefill_fn(Lb)
-                nxt, self.cache = fn(self.params, self.cache,
-                                     jnp.asarray(toks), jnp.asarray(start),
-                                     jnp.asarray(base),
-                                     jnp.asarray(newmask))
-            nxt = np.asarray(nxt)
+                nxt, finite, self.cache = fn(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(start), jnp.asarray(base),
+                    jnp.asarray(newmask))
+            nxt, finite = jax.device_get((nxt, finite))
+            self.nonfinite_logit_calls += int(not finite)
             now = self._now()
             self.metrics.prefill_calls += 1
             self.metrics.observe_timing("prefill_call", now - t0)
@@ -511,22 +527,25 @@ class ServingRuntime:
             cap = _obs.get_registry().snapshot()
         t0 = self._now()
         if self.paged is not None:
-            nxt, pool, state = self._decode_paged(
+            nxt, finite, pool, state = self._decode_paged(
                 self.params, self.paged.pool, self.paged.state,
                 self.paged.device_tables(), jnp.asarray(toks),
                 jnp.asarray(cur), jnp.asarray(active))
             self.paged.pool, self.paged.state = pool, state
         else:
-            nxt, self.cache = self._decode(
+            nxt, finite, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(cur), jnp.asarray(active))
-        nxt = np.asarray(nxt)
+        nxt, finite = jax.device_get((nxt, finite))
+        self.nonfinite_logit_calls += int(not finite)
         now = self._now()
         if cap is not None:
             d = _obs.get_registry().snapshot().diff(cap)
             self.decode_observed = {
                 "contractions": d.total("emulation.calls"),
                 "int8_gemms": d.total("emulation.int8_gemms"),
+                "int8_gemms_planned": d.total(
+                    "emulation.int8_gemms_planned"),
                 "int8_gemms_presplit": d.total("emulation.int8_gemms",
                                                presplit=1),
                 "highprec_adds": d.total("emulation.highprec_adds"),

@@ -199,6 +199,24 @@ def test_observed_counts_match_plan(spec, fresh_registry, operands):
         4 * (a.size + b.size)   # f32 operands, both sides split
 
 
+@pytest.mark.parametrize("spec", AUTO_SPECS)
+def test_planned_counts_match_jitted(spec, fresh_registry, operands):
+    """In a jitted program the static plan of each contraction is what
+    runs, with the rhs split in-call or frozen in the split cache."""
+    a, b = operands
+    cfg = ozimmu.parse_spec(spec)
+    jax.jit(lambda x, y: ozimmu.ozimmu_dot_general(x, y, DN, cfg))(a, b)
+    sp = split_cache.SplitCache().get(b, DN, cfg)
+    jax.jit(lambda x, s: ozimmu.ozimmu_dot_general(
+        x, b, DN, cfg, rhs_presplit=s))(a, sp)
+    snap = fresh_registry.snapshot()
+    for presplit in (0, 1):
+        ran = snap.total("emulation.int8_gemms", presplit=presplit)
+        assert ran > 0, (spec, presplit)
+        assert snap.total("emulation.int8_gemms_planned",
+                          presplit=presplit) == ran, (spec, presplit)
+
+
 def test_observed_counts_batched_and_presplit(fresh_registry):
     rng = np.random.default_rng(3)
     a = jnp.asarray(rng.standard_normal((3, 5, 64)), jnp.float32)

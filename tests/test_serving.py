@@ -426,6 +426,11 @@ def test_runtime_matches_reference_presplit(served):
     assert sc["weight_split_hit_rate"] == 1.0
     assert sc["avoided_split_bytes"] > 0
     assert sc["misses"] == len(wrappable_paths(params))
+    assert rt.nonfinite_logit_calls == 0
+    # the finite flag looks at live slots only
+    bad = jnp.zeros((2, cfg.vocab)).at[0, 3].set(jnp.nan)
+    assert bool(rt._greedy(bad, jnp.asarray([False, True]))[1])
+    assert not bool(rt._greedy(bad, jnp.asarray([True, True]))[1])
 
 
 def test_runtime_matches_reference_no_presplit(served):

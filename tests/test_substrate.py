@@ -200,23 +200,50 @@ def test_train_restart_equivalence(tmp_path):
     np.testing.assert_allclose(losses_resumed, losses_ref[2:], rtol=1e-5)
 
 
-def test_compile_cache_directory(monkeypatch):
+def test_compile_cache_directory(monkeypatch, tmp_path):
     """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR wins and
     no other directory is set; otherwise one fixed directory inside the
-    checkout.  (jax.config.update is recorded, not applied: the tests
-    never turn the cache on.)"""
+    checkout.  (The directory is redirected under tmp_path and
+    jax.config.update is recorded, not applied: the tests never turn the
+    cache on.)"""
     from pathlib import Path
 
-    from repro.launch import compile_cache
-    calls = []
-    monkeypatch.setattr(compile_cache.jax.config, "update",
-                        lambda name, value: calls.append((name, value)))
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/the/user")
-    assert compile_cache.enable_compile_cache() == "/placed/by/the/user"
-    assert calls == []
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    where = compile_cache.enable_compile_cache()
+    from repro.launch import entry
     checkout = Path(__file__).resolve().parents[1]
-    assert where == str(checkout / ".jax_cache")
+    assert entry.CHECKOUT_CACHE_DIR == checkout / ".jax_cache"
+    calls = []
+    monkeypatch.setattr(entry.jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setattr(entry, "CHECKOUT_CACHE_DIR", tmp_path / ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/the/user")
+    assert entry.enable_compile_cache() == "/placed/by/the/user"
+    assert calls == [] and not (tmp_path / ".jax_cache").exists()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    where = entry.enable_compile_cache()
+    assert where == str(tmp_path / ".jax_cache")
+    assert (tmp_path / ".jax_cache").is_dir()
     assert calls == [("jax_compilation_cache_dir", where)]
-    assert compile_cache.enable_compile_cache() == where
+    assert entry.enable_compile_cache() == where
+
+
+def test_exact_rounding_flag(monkeypatch):
+    """The entry points turn XLA's excess precision off before a backend
+    starts, and refuse to pretend they did once one has."""
+    from repro.launch import entry
+    flag = entry.EXACT_ROUNDING_FLAG
+    monkeypatch.setattr(entry.xla_bridge, "backends_are_initialized",
+                        lambda: False)
+    monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=x")
+    entry.require_exact_rounding()
+    assert os.environ["XLA_FLAGS"] == f"--xla_dump_to=x {flag}"
+    entry.require_exact_rounding()          # already there: unchanged
+    assert os.environ["XLA_FLAGS"] == f"--xla_dump_to=x {flag}"
+    monkeypatch.delenv("XLA_FLAGS")
+    entry.require_exact_rounding()
+    assert os.environ["XLA_FLAGS"] == flag
+    monkeypatch.setattr(entry.xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    monkeypatch.setenv("XLA_FLAGS", "")
+    with pytest.raises(RuntimeError, match="backend started"):
+        entry.require_exact_rounding()
+    assert os.environ["XLA_FLAGS"] == ""

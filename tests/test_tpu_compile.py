@@ -124,6 +124,19 @@ def test_fused_dot_general_compiles(one_chip):
                                   ((N, P), jnp.float32)))
 
 
+@pytest.mark.parametrize("spec", ["ozimmu_h-auto", "oz2_h-auto:fast2"])
+def test_f64_xla_path_not_compiled_for_v5e(one_chip, spec):
+    """The f64 XLA path (f64 operands, x64 on) does not compile for a v5e:
+    the TPU compiler cannot rewrite the splitter's s64 bitcast-convert.
+    If this starts failing, the f64 path runs on a v5e."""
+    assert jax.config.jax_enable_x64
+    cfg = parse_spec(spec)
+    with pytest.raises(Exception, match="X64 element types"):
+        _compiled_text(lambda a, b: ozimmu_dot_general(a, b, DN, cfg),
+                       one_chip, ((256, 256), jnp.float64),
+                       ((256, 256), jnp.float64))
+
+
 @pytest.mark.parametrize("spec", ["ozimmu_h-auto:fused",
                                   "oz2_h-auto:fast:f64:fused",
                                   "ozimmu_sm_h-8:fused@model"])
