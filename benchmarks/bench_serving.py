@@ -155,6 +155,7 @@ def main(out_json: Optional[str] = None, quick: bool = False):
     from benchmarks import model_v5e
     from repro import configs
     from repro.core import plan
+    from repro.core.engine import PresplitWeight
     from repro.models import api
     from repro.obs import registry as obs_registry
     from repro.serving import ServingRuntime
@@ -215,8 +216,14 @@ def main(out_json: Optional[str] = None, quick: bool = False):
         oz = cfg.engine.ozimmu_config
         if oz is not None and runtime.decode_observed is not None:
             dobs = runtime.decode_observed
-            n_frozen = (runtime.split_cache.stats.misses
-                        if runtime.split_cache is not None else 0)
+            # frozen weight matrices: each layer of a stacked leaf is
+            # one, since the decode count covers every scanned layer
+            n_frozen = sum(
+                int(np.prod(w.array.shape[:-2]))
+                for w in jax.tree_util.tree_leaves(
+                    runtime.params,
+                    is_leaf=lambda x: isinstance(x, PresplitWeight))
+                if isinstance(w, PresplitWeight))
             per_weight_modeled = plan.plan_contraction(
                 oz, SLOTS, cfg.d_model, cfg.d_model).int8_gemms
             modeled_step = n_frozen * per_weight_modeled

@@ -19,10 +19,10 @@ One chip runs three phases in one process:
 3. serve: internlm2_1_8b (8 layers, random weights from seed 0) through
    ``repro.launch.serve.main`` on ``ozimmu_h-auto:df32:fused``: every
    request finishes, the weight-split hit rate is 1, the int8 GEMM count
-   of the traced decode step equals the planner's static plan of the same
-   contractions, and the greedy tokens equal those of a second
-   run on the XLA spec ``ozimmu_h-auto:df32``; every logit the runtime
-   computed for a live slot is finite.
+   of one decode program (every layer and the head) equals the planner's
+   static plan of the same contractions, and the greedy tokens equal
+   those of a second run on the XLA spec ``ozimmu_h-auto:df32``; every
+   logit the runtime computed for a live slot is finite.
 
 Like every entry point, the script first calls
 ``repro.launch.entry.prepare_entry_point``: it turns XLA's excess
@@ -264,15 +264,15 @@ def serve_phase(opts=SERVE_OPTS, flags=SERVE_FLAGS,
     obs = fres["decode_observed"]
     check(obs is not None and obs["int8_gemms"] > 0
           and obs["int8_gemms"] == obs["int8_gemms_planned"],
-          f"{fused}: int8 GEMMs per traced decode step observed "
+          f"{fused}: int8 GEMMs per decode program observed "
           f"{obs and obs['int8_gemms']} != planned "
           f"{obs and obs['int8_gemms_planned']}")
     log(f"serve {fused}: {fres['summary']['requests']['finished']}/{n_req} "
         f"requests, {fres['summary']['tokens_generated']} tokens, "
         f"weight_split_hit_rate {sc['weight_split_hit_rate']:.2f}, int8 "
-        f"GEMMs per traced decode step observed {obs['int8_gemms']:.0f} == "
-        f"planned {obs['int8_gemms_planned']:.0f} (one scanned layer + "
-        f"head), logits finite; host wall: compile {fres['compile_s']:.1f}s"
+        f"GEMMs per decode program observed {obs['int8_gemms']:.0f} == "
+        f"planned {obs['int8_gemms_planned']:.0f}, logits finite; host "
+        f"wall: compile {fres['compile_s']:.1f}s"
         f", serve {fres['host_s']:.1f}s; peak HBM bytes so far "
         f"{peak_hbm_bytes()}")
 

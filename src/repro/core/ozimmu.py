@@ -210,7 +210,9 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     ``mesh="none"``.  Host-side only: runs
     once per eager call or per jit *trace* — a compiled step that traced
     through here replays the same contraction on every execution, so
-    trace-time counts are per-execution counts.  Costs come from the
+    trace-time counts are per-execution counts; a scan body, traced once
+    for all its steps, counts ``registry.repetitions()`` times (the
+    ``registry.repeated`` scopes around the scan).  Costs come from the
     same ``Plan`` accounting the planner uses, which is what makes
     observed == planned a testable invariant (tests/test_obs.py).
     Shapes/dtypes only — never touches values, so tracers stay clean and
@@ -235,16 +237,19 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
         mesh=cfg.mesh_axis if sharded else "none",
         presplit=int(presplit))
     reg = _obs.get_registry()
-    reg.inc("emulation.calls", 1, **labels)
-    reg.inc("emulation.int8_gemms", batch * pl.int8_gemms, **labels)
-    reg.inc("emulation.int8_gemms_planned", batch * static.int8_gemms,
+    # inside the trace of a scan body, one trace stands for every step
+    r = _obs.repetitions()
+    reg.inc("emulation.calls", r, **labels)
+    reg.inc("emulation.int8_gemms", r * batch * pl.int8_gemms, **labels)
+    reg.inc("emulation.int8_gemms_planned", r * batch * static.int8_gemms,
             **labels)
-    reg.inc("emulation.highprec_adds", batch * pl.highprec_adds, **labels)
+    reg.inc("emulation.highprec_adds", r * batch * pl.highprec_adds,
+            **labels)
     itemsize = np.dtype(a.dtype).itemsize
     split_elems = batch * m * n            # A is always split in-call;
     if not presplit:                       # B only when no frozen Split
         split_elems += batch * n * p
-    reg.inc("emulation.split_bytes", split_elems * itemsize, **labels)
+    reg.inc("emulation.split_bytes", r * split_elems * itemsize, **labels)
 
 
 _MESH_REDUCES = ("int32", "df32")

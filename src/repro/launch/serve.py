@@ -204,7 +204,7 @@ def main(argv=None):
               f"{pc['entries']} entries)")
     if runtime.decode_observed is not None:
         obs = runtime.decode_observed
-        print(f"[serve] observed per decode step: "
+        print(f"[serve] observed per decode program: "
               f"{obs['contractions']:.0f} contractions, "
               f"{obs['int8_gemms']:.0f} int8 GEMMs "
               f"({obs['int8_gemms_presplit']:.0f} on presplit weights; "

@@ -19,6 +19,7 @@ from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 
 # ---------------------------------------------------------------------------
@@ -199,9 +200,11 @@ def decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
                                cur_len=cur_len)
         return x, new_kv
 
-    x, (k_n, v_n) = lax.scan(
-        body, x, (params["dec_layers"], cache["k"], cache["v"],
-                  cache["cross_k"], cache["cross_v"]), length=cfg.n_layers)
+    with repeated(cfg.n_layers):
+        x, (k_n, v_n) = lax.scan(
+            body, x, (params["dec_layers"], cache["k"], cache["v"],
+                      cache["cross_k"], cache["cross_v"]),
+            length=cfg.n_layers)
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = L.logits_head(x, params["lm_head"], cfg.engine)
     new_cache = dict(cache)

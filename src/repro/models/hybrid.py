@@ -27,6 +27,7 @@ from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 _LRU_C = 8.0
 
@@ -297,8 +298,9 @@ def decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
         return x, nc
 
     block_caches = {k: cache[k] for k in ("conv", "lru", "k", "v")}
-    x, new_bc = lax.scan(body, x, (params["blocks"], block_caches),
-                         length=cfg.n_pattern_blocks)
+    with repeated(cfg.n_pattern_blocks):
+        x, new_bc = lax.scan(body, x, (params["blocks"], block_caches),
+                             length=cfg.n_pattern_blocks)
     tail_conv, tail_lru = [], []
     for i in range(cfg.n_tail_layers):
         rp = jax.tree.map(lambda a: a[i], params["tail_r"])

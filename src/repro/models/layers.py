@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.distributed.sharding import shard
+from repro.obs.registry import repeated
 
 NEG_INF = -1e30
 
@@ -154,7 +155,9 @@ def _flash_fwd_impl(q, k, v, engine, causal, window, q_chunk, kv_chunk,
         init = (jnp.full((B, KV, G, qc), NEG_INF, jnp.float32),
                 jnp.zeros((B, KV, G, qc), jnp.float32),
                 jnp.zeros((B, KV, G, qc, Dv), jnp.float32))
-        (m_run, l_run, acc), _ = lax.scan(kv_body, init, jnp.arange(nk))
+        with repeated(nk):
+            (m_run, l_run, acc), _ = lax.scan(kv_body, init,
+                                              jnp.arange(nk))
         out = acc / jnp.maximum(l_run, 1e-30)[..., None]
         # logsumexp per row; +inf on fully-masked (padding) rows so that
         # exp(s - lse) == 0 during backward recomputation
@@ -162,7 +165,8 @@ def _flash_fwd_impl(q, k, v, engine, causal, window, q_chunk, kv_chunk,
                         m_run + jnp.log(jnp.maximum(l_run, 1e-30)), jnp.inf)
         return None, (out, lse)  # (B, KV, G, qc, Dv), (B, KV, G, qc)
 
-    _, (outs, lses) = lax.scan(q_body, None, jnp.arange(nq))
+    with repeated(nq):
+        _, (outs, lses) = lax.scan(q_body, None, jnp.arange(nq))
     out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * qc, H, Dv)
     return out[:, :Lq].astype(q.dtype), (outs, lses)
 
@@ -229,11 +233,14 @@ def _flash_bwd_impl(q, k, v, outs, lses, dout, engine, causal, window,
         init = (dq_acc,
                 jnp.zeros((B, kc, KV, D), jnp.float32),
                 jnp.zeros((B, kc, KV, Dv), jnp.float32))
-        (dq_acc, dk_blk, dv_blk), _ = lax.scan(q_inner, init, jnp.arange(nq))
+        with repeated(nq):
+            (dq_acc, dk_blk, dv_blk), _ = lax.scan(q_inner, init,
+                                                   jnp.arange(nq))
         return dq_acc, (dk_blk, dv_blk)
 
     dq0 = jnp.zeros((B, nq, qc, KV, G, D), jnp.float32)
-    dq_acc, (dks, dvs) = lax.scan(kv_outer, dq0, jnp.arange(nk))
+    with repeated(nk):
+        dq_acc, (dks, dvs) = lax.scan(kv_outer, dq0, jnp.arange(nk))
     dq = dq_acc.reshape(B, nq * qc, H, D)[:, :Lq]
     dk = dks.transpose(1, 0, 2, 3, 4).reshape(B, nk * kc, KV, D)[:, :Lk]
     dv = dvs.transpose(1, 0, 2, 3, 4).reshape(B, nk * kc, KV, Dv)[:, :Lk]

@@ -23,6 +23,7 @@ from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 
 # ---------------------------------------------------------------------------
@@ -423,9 +424,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, cur_len):
             x, new_c = layer_fwd(lp, cfg, x, cos, sin, cache=(lat, kr),
                                  cur_len=cur_len)
             return x, new_c
-        x, (lat_n, kr_n) = lax.scan(
-            body, x, (params["layers"], cache["latent"], cache["k_rope"]),
-            length=cfg.n_layers)
+        with repeated(cfg.n_layers):
+            x, (lat_n, kr_n) = lax.scan(
+                body, x, (params["layers"], cache["latent"],
+                          cache["k_rope"]), length=cfg.n_layers)
         new_cache = {"latent": lat_n, "k_rope": kr_n}
     else:
         def body(x, inputs):
@@ -433,9 +435,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, cur_len):
             x, new_c = layer_fwd(lp, cfg, x, cos, sin, cache=(kc, vc),
                                  cur_len=cur_len)
             return x, new_c
-        x, (k_n, v_n) = lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]),
-            length=cfg.n_layers)
+        with repeated(cfg.n_layers):
+            x, (k_n, v_n) = lax.scan(
+                body, x, (params["layers"], cache["k"], cache["v"]),
+                length=cfg.n_layers)
         new_cache = {"k": k_n, "v": v_n}
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return L.logits_head(x, params["lm_head"], cfg.engine), new_cache

@@ -23,6 +23,7 @@ from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 
 def _dims(cfg: ModelConfig):
@@ -279,9 +280,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
                                        ssm_state=ssm)
         return x, (conv_n.astype(conv.dtype), ssm_n)
 
-    x, (conv_n, ssm_n) = lax.scan(
-        body, x, (params["layers"], cache["conv"], cache["ssm"]),
-        length=cfg.n_layers)
+    with repeated(cfg.n_layers):
+        x, (conv_n, ssm_n) = lax.scan(
+            body, x, (params["layers"], cache["conv"], cache["ssm"]),
+            length=cfg.n_layers)
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = L.logits_head(x, params["embed"].T, cfg.engine)
     return logits, {"conv": conv_n, "ssm": ssm_n}

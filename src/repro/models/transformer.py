@@ -13,6 +13,7 @@ from jax import lax
 from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +158,9 @@ def dense_layer(p, cfg, x, cos, sin, cache=None, cur_len=None):
 def scan_layers(body, stacked_params, x, xs=None, *, n_layers: int,
                 remat_block: int = 1):
     """scan ``body(layer_params, x, layer_xs) -> (x, ys)`` over the stacked
-    layer dim, rematerializing every ``remat_block`` layers."""
+    layer dim, rematerializing every ``remat_block`` layers.  Emulation
+    counts the body records while it traces stand for every layer
+    (``registry.repeated``)."""
     rb = max(1, remat_block)
     assert n_layers % rb == 0, (n_layers, rb)
 
@@ -167,7 +170,8 @@ def scan_layers(body, stacked_params, x, xs=None, *, n_layers: int,
 
     if rb == 1:
         step = jax.checkpoint(one)
-        x, ys = lax.scan(step, x, (stacked_params, xs), length=n_layers)
+        with repeated(n_layers):
+            x, ys = lax.scan(step, x, (stacked_params, xs), length=n_layers)
         return x, ys
 
     nb = n_layers // rb
@@ -179,9 +183,11 @@ def scan_layers(body, stacked_params, x, xs=None, *, n_layers: int,
     @jax.checkpoint
     def block(carry, inputs):
         bp, bxs = inputs
-        return lax.scan(one, carry, (bp, bxs), length=rb)
+        with repeated(rb):
+            return lax.scan(one, carry, (bp, bxs), length=rb)
 
-    x, ys = lax.scan(block, x, (blocked, xs_b), length=nb)
+    with repeated(nb):
+        x, ys = lax.scan(block, x, (blocked, xs_b), length=nb)
     ys = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
     return x, ys
 
@@ -248,9 +254,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
                                 cur_len=cur_len)
         return x, new_kv
 
-    x, (k_new, v_new) = lax.scan(body, x,
-                                 (params["layers"], cache["k"], cache["v"]),
-                                 length=cfg.n_layers)
+    with repeated(cfg.n_layers):
+        x, (k_new, v_new) = lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"]),
+            length=cfg.n_layers)
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = L.logits_head(x, params["lm_head"], cfg.engine)
     return logits, {"k": k_new, "v": v_new}

@@ -19,6 +19,7 @@ from repro.distributed.sharding import shard
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.common import ModelConfig, dense_param, init_stacked, stack_axes
+from repro.obs.registry import repeated
 
 
 # ---------------------------------------------------------------------------
@@ -135,9 +136,10 @@ def _group_fwd(gp, cfg, x, cos, sin, memory, *, self_cache=None,
             xc, kv = T.dense_layer(lp, cfg, xc, cos, sin, cache=(kc, vc),
                                    cur_len=cur_len)
             return xc, kv
-        x, new_kv = lax.scan(body, x,
-                             (gp["selfs"], self_cache[0], self_cache[1]),
-                             length=n_self)
+        with repeated(n_self):
+            x, new_kv = lax.scan(
+                body, x, (gp["selfs"], self_cache[0], self_cache[1]),
+                length=n_self)
     x = cross_block(gp["cross"], cfg, x, memory, kv_cache=cross_kv_cache)
     return x, new_kv
 
@@ -221,9 +223,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
         return x, new_kv
 
     ng = cfg.n_layers // cfg.cross_every
-    x, (k_n, v_n) = lax.scan(
-        body, x, (params["groups"], cache["k"], cache["v"],
-                  cache["cross_k"], cache["cross_v"]), length=ng)
+    with repeated(ng):
+        x, (k_n, v_n) = lax.scan(
+            body, x, (params["groups"], cache["k"], cache["v"],
+                      cache["cross_k"], cache["cross_v"]), length=ng)
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     logits = L.logits_head(x, params["lm_head"], cfg.engine)
     new_cache = dict(cache)

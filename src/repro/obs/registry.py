@@ -31,7 +31,8 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "MetricsRegistry", "Snapshot", "get_registry", "set_registry",
-    "enabled", "set_enabled", "disabled", "percentile", "hist_stats",
+    "enabled", "set_enabled", "disabled", "repeated", "repetitions",
+    "percentile", "hist_stats",
 ]
 
 # (metric name, canonicalised labels) — the registry's row key.  Labels
@@ -281,6 +282,7 @@ class MetricsRegistry:
 
 _REGISTRY = MetricsRegistry()
 _ENABLED = True  # mirrored module-level for the cheapest hot-path gate
+_REPEAT = threading.local()  # .n: the product of the open repeated() scopes
 
 
 def get_registry() -> MetricsRegistry:
@@ -304,6 +306,28 @@ def set_enabled(on: bool):
     global _ENABLED
     _ENABLED = bool(on)
     (_REGISTRY.enable if on else _REGISTRY.disable)()
+
+
+@contextlib.contextmanager
+def repeated(n: int):
+    """Mark the trace of a ``lax.scan`` of ``n`` steps (scopes nest
+    multiplicatively; per thread).  JAX traces the body once, so the
+    emulation counts it records (``emulation.*``, core/ozimmu.py) stand
+    for one step; the emulation recorder multiplies them by
+    ``repetitions()``, so they count every step the program executes.
+    Other counters (the planner's and the caches' mirrors) are not
+    multiplied: they count events of the host, not executions."""
+    prev = repetitions()
+    _REPEAT.n = prev * int(n)
+    try:
+        yield
+    finally:
+        _REPEAT.n = prev
+
+
+def repetitions() -> int:
+    """The product of the open ``repeated`` scopes of this thread."""
+    return getattr(_REPEAT, "n", 1)
 
 
 @contextlib.contextmanager

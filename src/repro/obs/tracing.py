@@ -1,4 +1,4 @@
-"""Profiler tracing: named emulation phases + run-level trace capture.
+"""Profiler tracing: named emulation phases, host spans, run-level capture.
 
 Two scope flavors, both no-ops when the obs layer is disabled:
 
@@ -10,14 +10,18 @@ Two scope flavors, both no-ops when the obs layer is disabled:
   contract (tests assert identity with obs on vs off).  Works for both
   the XLA path and the fused Pallas pipeline — the kernel calls are
   staged under the same scopes.
-* :func:`host_scope` — ``jax.profiler.TraceAnnotation``: brackets *host*
-  work (splitter dispatch, cache freezes) on the profiler timeline.
+* :func:`span` — ``jax.profiler.TraceAnnotation``: brackets *host* work
+  (the serving runtime's round and its phases, ``serve/*``) on the host
+  plane of the profiler's trace, on the device timeline's clock.  Integer
+  counts given as keyword arguments become the span's stats, recorded at
+  the same boundary as the span.  Nested spans on one thread nest in the
+  trace; with no trace running the annotation records nothing.
 
 Run-level capture: :func:`profile` brackets a whole run with
 ``jax.profiler.start_trace/stop_trace`` (the ``--profile-dir`` flag on
 serve/train).  A trace that was asked for and cannot start or stop
 raises: a run without the trace it was launched to capture is a failed
-run, not a quiet one.  Only :func:`host_scope` degrades silently.
+run, not a quiet one.  Only :func:`span` degrades silently.
 """
 
 from __future__ import annotations
@@ -29,10 +33,10 @@ import jax
 
 from repro.obs import registry as _registry
 
-__all__ = ["phase_scope", "host_scope", "profile", "PHASES"]
+__all__ = ["phase_scope", "span", "profile", "PHASES"]
 
 # The emulation pipeline's phase names (docs/observability.md): every
-# scope this module emits is ozimmu/<one of these>.
+# phase_scope is ozimmu/<one of these>.
 PHASES = ("split", "group_gemm", "ladder", "scale_accum")
 
 _NULL = contextlib.nullcontext()
@@ -46,13 +50,13 @@ def phase_scope(name: str):
     return jax.named_scope(f"ozimmu/{name}")
 
 
-def host_scope(name: str):
-    """Host-side profiler annotation (shows up on the python thread's
-    timeline during an active trace)."""
+def span(name: str, **counts: int):
+    """Host span ``name`` carrying ``counts`` as its stats (written only
+    while a trace runs)."""
     if not _registry.enabled():
         return _NULL
     try:
-        return jax.profiler.TraceAnnotation(f"ozimmu/{name}")
+        return jax.profiler.TraceAnnotation(name, **counts)
     except Exception:  # profiler backend unavailable
         return _NULL
 

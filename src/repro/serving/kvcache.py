@@ -394,12 +394,13 @@ class PagedKV:
         """jitted writer of ``n_span`` consecutive blocks of one slot
         (prefill write-back, starting at block operand ``row0/block``),
         memoized per span length on the instance (a functools.lru_cache
-        on the bound method would pin the pool)."""
+        on the bound method would pin the pool).  Its program is named
+        ``jit__write_span_impl``, apart from the prefill chunk's."""
         cached = self._span_fns.get(n_span)
         if cached is not None:
             return cached
 
-        def impl(pool, cache, slot_idx, block_ids, row0):
+        def _write_span_impl(pool, cache, slot_idx, block_ids, row0):
             out = dict(pool)
             for name in self.paged_names:
                 pleaf, cleaf = pool[name], cache[name]
@@ -415,7 +416,7 @@ class PagedKV:
                 out[name] = pleaf.at[sl + (block_ids,)].set(
                     span.astype(pleaf.dtype))
             return out
-        fn = self._span_fns[n_span] = jax.jit(impl)
+        fn = self._span_fns[n_span] = jax.jit(_write_span_impl)
         return fn
 
     # -- host-side block management --------------------------------------

@@ -56,6 +56,7 @@ from repro.models import api
 from repro.serving import presplit as presplit_mod
 from repro.serving.kvcache import PagedKV, SlotCacheOps, STATE_DESCRIPTORS
 from repro.obs import registry as _obs
+from repro.obs.tracing import span
 from repro.serving.metrics import ServingMetrics
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import Request, Scheduler
@@ -127,11 +128,13 @@ class ServingRuntime:
         self.ops = SlotCacheOps(cfg, self.model)
         self.metrics = ServingMetrics(now=now)
         self._now = now
-        # trace-time emulation counts of ONE decode step (captured around
-        # the first, compiling, decode call — a compiled step replays the
-        # same contractions every execution).  None until a step traced
-        # with obs enabled; persists across reset_metrics.
-        self.decode_observed: Optional[Dict[str, float]] = None
+        # emulation counts of ONE execution of each jitted step, keyed
+        # "decode", Lb or ("paged", Lb) like the programs: the registry
+        # diff over the call that traced it (a compiled step replays the
+        # same contractions every execution, and a scanned body counts
+        # every step it runs, see registry.repeated).  Filled as steps
+        # trace with obs enabled; persists across reset_metrics.
+        self.observed: Dict[Any, Dict[str, int]] = {}
         # prefill/decode calls whose logits of a live slot held a NaN or
         # an inf (greedy argmax would pick a token from them silently)
         self.nonfinite_logit_calls = 0
@@ -193,6 +196,12 @@ class ServingRuntime:
         self._presplit_counts0 = presplit_trace_counts()
         self._presplit_rate = None    # measured once steps have traced
 
+    @property
+    def decode_observed(self) -> Optional[Dict[str, int]]:
+        """Emulation counts of one execution of the decode program (None
+        until it traced with obs enabled)."""
+        return self.observed.get("decode")
+
     # ------------------------------------------------------------------
     # jitted step bodies
     # ------------------------------------------------------------------
@@ -247,7 +256,8 @@ class ServingRuntime:
                                                cur)
             return c, logits[:, -1]
 
-        cache, logits = jax.lax.scan(body, cache, jnp.arange(Lb))
+        with _obs.repeated(Lb):
+            cache, logits = jax.lax.scan(body, cache, jnp.arange(Lb))
         nxt, finite = self._greedy(logits[-1], newmask)
         return nxt, finite, cache
 
@@ -277,6 +287,31 @@ class ServingRuntime:
                                                           newmask)
             fn = self._prefill_fns[("paged", Lb)] = jax.jit(impl)
         return fn
+
+    def _call_observed(self, key, fn, *args):
+        """``fn(*args)``; the first call of ``key`` with obs enabled keeps
+        the emulation counts its trace recorded in ``observed``."""
+        if key in self.observed or not _obs.enabled():
+            return fn(*args)
+        cap = _obs.get_registry().snapshot()
+        out = fn(*args)
+        d = _obs.get_registry().snapshot().diff(cap)
+        self.observed[key] = {
+            "contractions": int(d.total("emulation.calls")),
+            "int8_gemms": int(d.total("emulation.int8_gemms")),
+            "int8_gemms_planned": int(d.total(
+                "emulation.int8_gemms_planned")),
+            "int8_gemms_presplit": int(d.total("emulation.int8_gemms",
+                                               presplit=1)),
+            "highprec_adds": int(d.total("emulation.highprec_adds")),
+        }
+        return out
+
+    def _gemm_arg(self, key) -> Dict[str, int]:
+        """The ``int8_gemms`` span argument of one execution of ``key``'s
+        program, once known."""
+        obs = self.observed.get(key)
+        return {} if obs is None else {"int8_gemms": obs["int8_gemms"]}
 
     # ------------------------------------------------------------------
     # host loop
@@ -396,85 +431,98 @@ class ServingRuntime:
         if not plans:
             return
         for Lb, group in self.sched.chunk_groups(plans):
-            # paged: allocate blocks for the chunk first (may evict
-            # group members — drop those from this call), then privatize
-            # any shared block the write-back span will touch
-            ready = []
-            for slot, req, clen in group:
-                if self.sched.slots[slot].request is not req:
-                    continue    # evicted by an earlier bucket this round
-                done = self.sched.slots[slot].prefilled
-                if not self._alloc_or_evict(slot, done + clen):
+            with span("serve/prefill/prepare"):
+                ready = self._prepare_chunk(group)
+                if not ready:
                     continue
-                if self.paged is not None and self.paged.paged_names:
-                    length, start = self._span_args(done, clen)
-                    b0 = start // self.paged.block
-                    nb = -(-length // self.paged.block)
-                    if not self._cow_or_evict(slot, range(b0, nb)):
-                        continue
-                ready.append((slot, req, clen))
-            # a later allocation may have evicted an earlier group member
-            ready = [(s, r, c) for s, r, c in ready
-                     if self.sched.slots[s].request is r]
-            if not ready:
-                continue
-            toks = np.zeros((self.n_slots, Lb), np.int32)
-            start = np.full((self.n_slots,), Lb, np.int32)
-            base = np.zeros((self.n_slots,), np.int32)
-            newmask = np.zeros((self.n_slots,), bool)
-            for slot, req, clen in ready:
-                done = self.sched.slots[slot].prefilled
-                pt = req.prefill_tokens()
-                toks[slot, Lb - clen:] = pt[done:done + clen]
-                start[slot] = Lb - clen
-                base[slot] = done
-                newmask[slot] = True
-            t0 = self._now()
-            if self.paged is not None:
-                fn = self._prefill_paged_fn(Lb)
-                nxt, finite, after = fn(self.params, self.paged.pool,
-                                        self.paged.state,
-                                        self.paged.device_tables(),
-                                        jnp.asarray(toks),
-                                        jnp.asarray(start),
-                                        jnp.asarray(base),
-                                        jnp.asarray(newmask))
+                toks = np.zeros((self.n_slots, Lb), np.int32)
+                start = np.full((self.n_slots,), Lb, np.int32)
+                base = np.zeros((self.n_slots,), np.int32)
+                newmask = np.zeros((self.n_slots,), bool)
+                fed = 0
                 for slot, req, clen in ready:
                     done = self.sched.slots[slot].prefilled
-                    length, span_start = self._span_args(done, clen)
-                    self.paged.write_slot_prefix(slot, after, length,
-                                                 start=span_start)
-                self.paged.set_state_from(after)
-            else:
-                fn = self._prefill_fn(Lb)
-                nxt, finite, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(start), jnp.asarray(base),
-                    jnp.asarray(newmask))
-            nxt, finite = jax.device_get((nxt, finite))
-            self.nonfinite_logit_calls += int(not finite)
-            now = self._now()
-            self.metrics.prefill_calls += 1
-            self.metrics.observe_timing("prefill_call", now - t0)
-            # every scanned position consumes every frozen weight split
-            self._avoided_split_bytes += Lb * self._wrapped_bytes
-            for slot, req, clen in ready:
-                done = self.sched.slots[slot].prefilled
-                total = len(req.prefill_tokens())
-                self.metrics.prefill_tokens += clen
-                if done + clen < total:
-                    self.sched.on_chunk(slot, clen)
-                    self.metrics.prefill_chunks += 1
-                    self._maybe_publish(slot, req)
+                    pt = req.prefill_tokens()
+                    toks[slot, Lb - clen:] = pt[done:done + clen]
+                    start[slot] = Lb - clen
+                    base[slot] = done
+                    newmask[slot] = True
+                    fed += clen
+            key = Lb if self.paged is None else ("paged", Lb)
+            t0 = self._now()
+            with span("serve/prefill/dispatch", tokens=fed, bucket=Lb,
+                      **self._gemm_arg(key)):
+                if self.paged is not None:
+                    nxt, finite, after = self._call_observed(
+                        key, self._prefill_paged_fn(Lb), self.params,
+                        self.paged.pool, self.paged.state,
+                        self.paged.device_tables(), jnp.asarray(toks),
+                        jnp.asarray(start), jnp.asarray(base),
+                        jnp.asarray(newmask))
+                    for slot, req, clen in ready:
+                        done = self.sched.slots[slot].prefilled
+                        length, span_start = self._span_args(done, clen)
+                        self.paged.write_slot_prefix(slot, after, length,
+                                                     start=span_start)
+                    self.paged.set_state_from(after)
+                else:
+                    nxt, finite, self.cache = self._call_observed(
+                        key, self._prefill_fn(Lb), self.params, self.cache,
+                        jnp.asarray(toks), jnp.asarray(start),
+                        jnp.asarray(base), jnp.asarray(newmask))
+            with span("serve/prefill/wait"):
+                nxt, finite = jax.device_get((nxt, finite))
+            with span("serve/prefill/commit"):
+                self._commit_chunk(ready, nxt, finite, Lb, t0)
+
+    def _prepare_chunk(self, group) -> List[Tuple[int, Request, int]]:
+        """The members of one bucket group that can run: paged, allocate
+        blocks for the chunk first (may evict group members — drop those
+        from this call), then privatize any shared block the write-back
+        span will touch."""
+        ready = []
+        for slot, req, clen in group:
+            if self.sched.slots[slot].request is not req:
+                continue    # evicted by an earlier bucket this round
+            done = self.sched.slots[slot].prefilled
+            if not self._alloc_or_evict(slot, done + clen):
+                continue
+            if self.paged is not None and self.paged.paged_names:
+                length, start = self._span_args(done, clen)
+                b0 = start // self.paged.block
+                nb = -(-length // self.paged.block)
+                if not self._cow_or_evict(slot, range(b0, nb)):
                     continue
-                self.metrics.tokens_generated += 1  # the first new token
-                finished = self.sched.on_prefilled(slot, int(nxt[slot]),
-                                                   now)
-                self._cur[slot] = self.sched.slots[slot].pos + 1 \
-                    if not finished else 1
-                self._last_tok[slot] = int(nxt[slot])
-                if finished:
-                    self._finish(slot, req, now)
+            ready.append((slot, req, clen))
+        # a later allocation may have evicted an earlier group member
+        return [(s, r, c) for s, r, c in ready
+                if self.sched.slots[s].request is r]
+
+    def _commit_chunk(self, ready, nxt, finite, Lb: int, t0: float):
+        """Scheduler updates after one chunk call: progress, or the first
+        token of each slot whose prefill completed."""
+        self.nonfinite_logit_calls += int(not finite)
+        now = self._now()
+        self.metrics.prefill_calls += 1
+        self.metrics.observe_timing("prefill_call", now - t0)
+        # every scanned position consumes every frozen weight split
+        self._avoided_split_bytes += Lb * self._wrapped_bytes
+        for slot, req, clen in ready:
+            done = self.sched.slots[slot].prefilled
+            total = len(req.prefill_tokens())
+            self.metrics.prefill_tokens += clen
+            if done + clen < total:
+                self.sched.on_chunk(slot, clen)
+                self.metrics.prefill_chunks += 1
+                self._maybe_publish(slot, req)
+                continue
+            self.metrics.tokens_generated += 1  # the first new token
+            finished = self.sched.on_prefilled(slot, int(nxt[slot]), now)
+            self._cur[slot] = self.sched.slots[slot].pos + 1 \
+                if not finished else 1
+            self._last_tok[slot] = int(nxt[slot])
+            if finished:
+                self._finish(slot, req, now)
 
     def _maybe_publish(self, slot: int, req: Request):
         """Publish the frozen prefix when a chunk boundary lands exactly
@@ -492,89 +540,88 @@ class ServingRuntime:
             self.paged.free_slot(slot)
         self.metrics.record_finish(req, now)
 
-    def _do_decode(self):
+    def _decode_ready(self) -> List[int]:
+        """The slots that decode this step; paged, each has ``cur``
+        positions allocated (this step writes row cur-1) and the written
+        block privatized."""
         active_idx = self.sched.decode_slots()
-        if not active_idx:
-            return
-        if self.paged is not None:
-            # this step writes row cur-1, so the slot needs `cur`
-            # positions allocated, and the written block privatized
-            survivors = []
-            for slot in active_idx:
-                if self.sched.slots[slot].request is None:
-                    continue    # evicted back by pressure from a peer
-                cur = int(self._cur[slot])
-                if not self._alloc_or_evict(slot, cur):
+        if self.paged is None or not active_idx:
+            return active_idx
+        survivors = []
+        for slot in active_idx:
+            if self.sched.slots[slot].request is None:
+                continue    # evicted back by pressure from a peer
+            cur = int(self._cur[slot])
+            if not self._alloc_or_evict(slot, cur):
+                continue
+            if self.paged.paged_names:
+                pos = (cur - 1) % self.paged.seq_len
+                if not self._cow_or_evict(slot, [pos // self.paged.block]):
                     continue
-                if self.paged.paged_names:
-                    pos = (cur - 1) % self.paged.seq_len
-                    if not self._cow_or_evict(slot,
-                                              [pos // self.paged.block]):
-                        continue
-                survivors.append(slot)
-            active_idx = [s for s in survivors
-                          if self.sched.slots[s].request is not None]
+            survivors.append(slot)
+        return [s for s in survivors
+                if self.sched.slots[s].request is not None]
+
+    def _do_decode(self):
+        with span("serve/decode/prepare"):
+            active_idx = self._decode_ready()
             if not active_idx:
                 return
-        active = np.zeros((self.n_slots,), bool)
-        active[active_idx] = True
-        # per-slot position of the token being written this step; 0 for
-        # idle slots = "write nothing" (cache_update_row no-op)
-        cur = np.where(active, self._cur, 0).astype(np.int32)
-        toks = self._last_tok[:, None].astype(np.int32)
-        cap = None
-        if self.decode_observed is None and _obs.enabled():
-            cap = _obs.get_registry().snapshot()
+            active = np.zeros((self.n_slots,), bool)
+            active[active_idx] = True
+            # per-slot position of the token being written this step; 0
+            # for idle slots = "write nothing" (cache_update_row no-op)
+            cur = np.where(active, self._cur, 0).astype(np.int32)
+            toks = self._last_tok[:, None].astype(np.int32)
         t0 = self._now()
-        if self.paged is not None:
-            nxt, finite, pool, state = self._decode_paged(
-                self.params, self.paged.pool, self.paged.state,
-                self.paged.device_tables(), jnp.asarray(toks),
-                jnp.asarray(cur), jnp.asarray(active))
-            self.paged.pool, self.paged.state = pool, state
-        else:
-            nxt, finite, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(cur), jnp.asarray(active))
-        nxt, finite = jax.device_get((nxt, finite))
-        self.nonfinite_logit_calls += int(not finite)
-        now = self._now()
-        if cap is not None:
-            d = _obs.get_registry().snapshot().diff(cap)
-            self.decode_observed = {
-                "contractions": d.total("emulation.calls"),
-                "int8_gemms": d.total("emulation.int8_gemms"),
-                "int8_gemms_planned": d.total(
-                    "emulation.int8_gemms_planned"),
-                "int8_gemms_presplit": d.total("emulation.int8_gemms",
-                                               presplit=1),
-                "highprec_adds": d.total("emulation.highprec_adds"),
-            }
-        self.metrics.decode_steps += 1
-        self.metrics.observe_timing("decode_step", now - t0)
-        self._avoided_split_bytes += self._wrapped_bytes
-        for slot in active_idx:
-            req = self.sched.slots[slot].request
-            self.metrics.tokens_generated += 1
-            finished = self.sched.on_token(slot, int(nxt[slot]), now)
-            if finished:
-                self._finish(slot, req, now)
+        with span("serve/decode/dispatch", slots=len(active_idx),
+                  **self._gemm_arg("decode")):
+            if self.paged is not None:
+                nxt, finite, pool, state = self._call_observed(
+                    "decode", self._decode_paged, self.params,
+                    self.paged.pool, self.paged.state,
+                    self.paged.device_tables(), jnp.asarray(toks),
+                    jnp.asarray(cur), jnp.asarray(active))
+                self.paged.pool, self.paged.state = pool, state
             else:
-                self._cur[slot] = self.sched.slots[slot].pos + 1
-                self._last_tok[slot] = int(nxt[slot])
+                nxt, finite, self.cache = self._call_observed(
+                    "decode", self._decode, self.params, self.cache,
+                    jnp.asarray(toks), jnp.asarray(cur),
+                    jnp.asarray(active))
+        with span("serve/decode/wait"):
+            nxt, finite = jax.device_get((nxt, finite))
+        with span("serve/decode/commit"):
+            self.nonfinite_logit_calls += int(not finite)
+            now = self._now()
+            self.metrics.decode_steps += 1
+            self.metrics.observe_timing("decode_step", now - t0)
+            self._avoided_split_bytes += self._wrapped_bytes
+            for slot in active_idx:
+                req = self.sched.slots[slot].request
+                self.metrics.tokens_generated += 1
+                finished = self.sched.on_token(slot, int(nxt[slot]), now)
+                if finished:
+                    self._finish(slot, req, now)
+                else:
+                    self._cur[slot] = self.sched.slots[slot].pos + 1
+                    self._last_tok[slot] = int(nxt[slot])
 
     def step(self) -> bool:
         """One scheduler round: admit new requests, feed one prefill
         chunk per pending slot, then decode one token for every
-        fully-prefilled slot.  Returns False when idle."""
+        fully-prefilled slot.  Returns False when idle.  With a profiler
+        trace running, the round and its phases are host spans
+        (``serve/*``, docs/observability.md)."""
         if self.sched.all_done:
             return False
-        self.metrics.start()
-        self.metrics.sample_queue(self.sched.queue_depth)
-        for slot, req in self.sched.admit():
-            self._on_admit(slot, req)
-        self._do_prefill_round()
-        self._do_decode()
+        with span("serve/step"):
+            self.metrics.start()
+            self.metrics.sample_queue(self.sched.queue_depth)
+            with span("serve/admit"):
+                for slot, req in self.sched.admit():
+                    self._on_admit(slot, req)
+            self._do_prefill_round()
+            self._do_decode()
         return True
 
     def run(self, max_steps: Optional[int] = None) -> Dict[str, Any]:

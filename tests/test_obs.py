@@ -18,9 +18,11 @@ Covers:
 * the planner audit ledger: one decision row per auto-k resolution
   with the spec, mode, chosen k, predicted eps and cost columns;
 * split-cache hit/miss mirroring into the global registry;
-* exporters: Prometheus text passes the format lint and round-trips
-  through `parse_prometheus`; the JSON document exposes the `totals`
-  surface the CI smoke asserts on.
+* `registry.repeated`: emulation counts recorded inside a scope are
+  multiplied, scopes nest, other counters record as before;
+* the exporter: the JSON document exposes the `totals` surface the CI
+  smoke asserts on;
+* host spans: `tracing.span` degrades to a no-op without a profiler.
 """
 import json
 import os
@@ -126,6 +128,34 @@ def test_disabled_mode_records_nothing(fresh_registry):
     assert registry.enabled()
     fresh_registry.inc("c", 1)
     assert fresh_registry.value("c") == 1
+
+
+def test_repeated_scopes_multiply_and_nest(fresh_registry, operands):
+    """Emulation counts recorded under ``repeated(n)`` count n times (a
+    scan body traced once stands for every step); scopes nest
+    multiplicatively.  The planner's mirror of its ledger and plain
+    counters record as before: one ``plan.decisions`` per ledger row."""
+    a, b = operands
+    cfg = ozimmu.parse_spec("ozimmu_h-auto:df32")
+    plan.get_ledger().clear()
+    ozimmu.ozimmu_dot_general(a, b, DN, cfg)
+    first = fresh_registry.snapshot()
+    once = first.total("emulation.int8_gemms")
+    once_planned = first.total("emulation.int8_gemms_planned")
+    assert registry.repetitions() == 1
+    with registry.repeated(3):
+        with registry.repeated(4):
+            assert registry.repetitions() == 12
+            ozimmu.ozimmu_dot_general(a, b, DN, cfg)
+            fresh_registry.inc("c", 1)
+        assert registry.repetitions() == 3
+    assert registry.repetitions() == 1
+    snap = fresh_registry.snapshot()
+    assert snap.total("emulation.int8_gemms") == 13 * once
+    assert snap.total("emulation.int8_gemms_planned") == 13 * once_planned
+    assert snap.total("emulation.calls") == 13
+    assert fresh_registry.value("c") == 1
+    assert snap.total("plan.decisions") == len(plan.get_ledger()) == 2
 
 
 def test_percentile_linear_interpolation():
@@ -393,30 +423,8 @@ def test_split_cache_obs_counters(fresh_registry, operands):
 
 
 # ---------------------------------------------------------------------------
-# exporters
+# exporter
 # ---------------------------------------------------------------------------
-
-def test_prometheus_roundtrip_and_lint():
-    reg = MetricsRegistry()
-    reg.inc("emulation.int8_gemms", 45, variant="oz2_h", k=9)
-    reg.inc("emulation.int8_gemms", 10, variant="ozimmu_h", k=4)
-    reg.gauge("split_cache.cached_bytes", 1024)
-    for v in (0.1, 0.2, 0.4):
-        reg.observe("serving.decode_step_s", v)
-    text = export.to_prometheus(reg.snapshot(), prefix="repro")
-    export.lint_prometheus(text)        # raises on any format violation
-    parsed = export.parse_prometheus(text)
-    assert parsed[
-        'repro_emulation_int8_gemms_total{k="9",variant="oz2_h"}'] == 45
-    assert parsed["repro_split_cache_cached_bytes"] == 1024
-    assert parsed["repro_serving_decode_step_s_count"] == 3
-    assert parsed['repro_serving_decode_step_s{quantile="0.5"}'] == 0.2
-    # the lint rejects malformed text
-    with pytest.raises(ValueError):
-        export.lint_prometheus("no_type_line 1")
-    with pytest.raises(ValueError):
-        export.lint_prometheus("# TYPE x counter\nx{bad-label=\"1\"} 1")
-
 
 def test_json_document_totals_and_ledger(fresh_registry, operands):
     a, b = operands
@@ -474,9 +482,9 @@ def test_profile_raises_when_trace_cannot_stop(monkeypatch, tmp_path):
 def test_host_scope_degrades_without_profiler(monkeypatch):
     from repro.obs import tracing
 
-    def refuse(name):
+    def refuse(name, **counts):
         raise RuntimeError("no profiler backend")
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
-    with tracing.host_scope("split"):
+    with tracing.span("serve/step", slots=2):
         pass
