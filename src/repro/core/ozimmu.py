@@ -69,8 +69,6 @@ import dataclasses
 import functools
 import math
 from functools import partial
-from functools import partial as partial_fn  # alias: `partial` is also a
-                                             # keyword arg of _bmm_local
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -223,6 +221,11 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     before k was fixed: in a jitted program, where auto-k resolves
     statically and a frozen split carries the k it froze, it must equal
     ``emulation.int8_gemms``.
+
+    ``emulation.kernel_operand_copy_bytes`` (Pallas paths only) counts the
+    digit copies the group-GEMM wrapper makes for its kernel
+    (``kernels.ops.operand_copy_bytes``): 0 for int8 digits, whose slice
+    pairs the kernel reads in place; the bf16 decode for sign-magnitude.
     """
     from repro.core import plan as _plan
     m, n = a.shape[-2], a.shape[-1]
@@ -250,6 +253,12 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     if not presplit:                       # B only when no frozen Split
         split_elems += batch * n * p
     reg.inc("emulation.split_bytes", r * split_elems * itemsize, **labels)
+    if cfg.use_pallas:
+        from repro.kernels import ops as kops  # lazy: kernels are optional
+        reg.inc("emulation.kernel_operand_copy_bytes",
+                r * kops.operand_copy_bytes(
+                    cfg.k, batch, m, n, p,
+                    signmag=splitting.is_signmag(cfg.split)), **labels)
 
 
 _MESH_REDUCES = ("int32", "df32")
@@ -422,12 +431,14 @@ def _bmm_local(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, *,
     group_gemm_fn = scale_accum_fn = pair_gemm_fn = unscale_fn = None
     if cfg.use_pallas:
         from repro.kernels import ops as kops  # lazy: kernels are optional
+        # the kernel's digit stacks are prepared once per contraction
+        gemm = kops.group_gemm_fn(sa, sb)
         if cfg.accumulate == "naive":
             # naive accumulation has no groups; each slice pair runs as a
             # G=1 Pallas GEMM (bit-identical to the XLA dot_general)
-            pair_gemm_fn = lambda s, t: kops.group_gemm(sa, sb, [(s, t)])
+            pair_gemm_fn = lambda s, t: gemm([(s, t)])
         else:
-            group_gemm_fn = partial_fn(kops.group_gemm, sa, sb)
+            group_gemm_fn = gemm
         if cfg.use_pallas == "fused":
             scale_accum_fn = (kops.oz2_scale_accum_update
                               if cfg.accumulate == "oz2"

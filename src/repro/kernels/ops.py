@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles: padding to tile multiples, row-scale preparation, slice-pair
-stacking for group GEMMs, batch flattening onto the kernels' leading grid
+index maps for group GEMMs, batch flattening onto the kernels' leading grid
 axis, and the choice between compiled and interpreted kernels.  Block
 sizes come from the planner's static-shape autotune table
 (``repro.core.plan.kernel_blocks``), aligned per kernel with
@@ -167,19 +167,20 @@ def split_fused(a: jax.Array, k: int, beta: int, *, mode: str = "rn_const",
                  gbase=gbase, signmag=(mode == "sm"))
 
 
-def group_gemm(sa: Split, sb: Split, pairs: Sequence[Tuple[int, int]]
-               ) -> jax.Array:
-    """sum over slice pairs of A_s @ B_t in int32 via the Pallas kernel.
+def group_gemm_fn(sa: Split, sb: Split
+                  ) -> Callable[[Sequence[Tuple[int, int]]], jax.Array]:
+    """The ``group_gemm_fn`` hook of one contraction: ``pairs -> int32``,
+    sum over the slice pairs of A_s @ B_t via the Pallas kernel.
 
-    Signature matches the ``group_gemm_fn`` hook in
-    :func:`repro.core.accumulate.matmul_group_ef` (after partial application
-    of sa, sb).  Batched splits — digits ``(k, *batch, m, n)`` — map onto
-    the kernel's leading batch grid axis (flattened to one axis, restored
-    on exit); output is ``(*batch, m, p)``.
+    The digit stacks are prepared once, here, for every group the hook
+    runs: batched digits ``(k, *batch, m, n)`` flatten onto the kernel's
+    leading batch grid axis (a free reshape), and each stack is padded
+    once to the tile multiples.  Each call then hands the kernel the
+    group's slice indices and the kernel's index maps read the pairs
+    straight from the stacks: no per-group gather, transpose or pad.
+    Output is ``(*batch, m, p)``.
     """
-    idx_a = [s - 1 for s, _ in pairs]
-    idx_b = [t - 1 for _, t in pairs]
-    # sign-magnitude digits decode to [-128, 255] before the gather; the
+    # sign-magnitude digits decode to [-128, 255] once over the stack; the
     # MXU has no int16 path, so they enter the kernel as bf16 (exact for
     # these integers) and each block product accumulates exactly in f32
     # (see _SM_MAX_BN) before joining the int32 tile
@@ -188,24 +189,46 @@ def group_gemm(sa: Split, sb: Split, pairs: Sequence[Tuple[int, int]]
     db = sm_decode(sb.digits) if sb.signmag else sb.digits
     if signmag:
         da, db = da.astype(jnp.bfloat16), db.astype(jnp.bfloat16)
-    a8 = da[jnp.asarray(idx_a)]             # (G, *batch, m, n)
-    b8 = db[jnp.asarray(idx_b)]
-    G = a8.shape[0]
-    batch = a8.shape[1:-2]
-    m, n = a8.shape[-2], a8.shape[-1]
-    p = b8.shape[-1]
-    a8 = jnp.moveaxis(a8, 0, -3).reshape((-1, G, m, n))
-    b8 = jnp.moveaxis(b8, 0, -3).reshape((-1, G, n, p))
+    batch = da.shape[1:-2]
+    m, n = da.shape[-2:]
+    p = db.shape[-1]
     bm_pref, bn_pref, bp_pref = plan.kernel_blocks(m, n, p)
     bm = plan.tile(m, bm_pref, 128)
     bn = plan.tile(n, min(bn_pref, _SM_MAX_BN) if signmag else bn_pref,
                    128)
     bp = plan.tile(p, bp_pref, 128)
-    a8 = _pad_to(a8, (1, 1, bm, bn))
-    b8 = _pad_to(b8, (1, 1, bn, bp))
-    with _tracing.phase_scope("kernel/group_gemm"):
-        out = _pallas(_gg.group_gemm, a8, b8, bm=bm, bp=bp, bn=bn)
-    return out[:, :m, :p].reshape(batch + (m, p))
+    a = _pad_to(da.reshape((da.shape[0], -1, m, n)), (1, 1, bm, bn))
+    b = _pad_to(db.reshape((db.shape[0], -1, n, p)), (1, 1, bn, bp))
+
+    def gemm(pairs: Sequence[Tuple[int, int]]) -> jax.Array:
+        ia = jnp.asarray([s - 1 for s, _ in pairs], jnp.int32)
+        ib = jnp.asarray([t - 1 for _, t in pairs], jnp.int32)
+        with _tracing.phase_scope("kernel/group_gemm"):
+            out = _pallas(_gg.group_gemm, ia, ib, a, b, bm=bm, bp=bp, bn=bn)
+        return out[:, :m, :p].reshape(batch + (m, p))
+
+    return gemm
+
+
+def group_gemm(sa: Split, sb: Split, pairs: Sequence[Tuple[int, int]]
+               ) -> jax.Array:
+    """sum over slice pairs of A_s @ B_t in int32 via the Pallas kernel:
+    one group of :func:`group_gemm_fn`.  A contraction with several groups
+    builds the hook once instead, so its stacks are prepared once."""
+    return group_gemm_fn(sa, sb)(pairs)
+
+
+def operand_copy_bytes(k: int, batch: int, m: int, n: int, p: int, *,
+                       signmag: bool) -> int:
+    """Bytes of digit copies :func:`group_gemm_fn` makes for the kernel in
+    one contraction of ``batch`` (m, n) @ (n, p) products split into k
+    slices: the bf16 decode of sign-magnitude stacks.  Int8 stacks are
+    read by the kernel as they are (beyond the one pad of each stack to
+    the tile multiples), so nothing is gathered, transposed or padded per
+    group."""
+    if not signmag:
+        return 0
+    return k * batch * (m * n + n * p) * jnp.dtype(jnp.bfloat16).itemsize
 
 
 def _epilogue_operands(p32: jax.Array, srow: jax.Array, scol: jax.Array,

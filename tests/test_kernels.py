@@ -85,9 +85,51 @@ def test_split_fused_property_padding(m, n, k, seed, mode):
 def test_group_gemm_matches_ref(rng, G, m, n, p):
     a8 = jnp.asarray(rng.integers(-127, 128, (G, m, n)), jnp.int8)
     b8 = jnp.asarray(rng.integers(-127, 128, (G, n, p)), jnp.int8)
-    got = raw_group_gemm(a8, b8, bm=128, bp=128, bn=128, interpret=True)
+    idx = jnp.arange(G, dtype=jnp.int32)
+    got = raw_group_gemm(idx, idx, a8, b8, bm=128, bp=128, bn=128,
+                         interpret=True)
     want = ref.group_gemm_ref(a8, b8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# slice-pair lists of k = 6 splits as the accumulators hand them over
+_PAIR_LISTS = {
+    # one anti-diagonal group (g = 7) chunked at r = 4
+    "antidiagonal_cut_at_r": [(1, 6), (2, 5), (3, 4), (4, 3)],
+    # the oz2 fast band s + t <= k + 1 as one list
+    "oz2_fast_band": [(s, t) for s in range(1, 7) for t in range(1, 8 - s)],
+    "single_pair": [(3, 3)],
+    "descending_t": [(1, 6), (2, 4), (4, 2), (6, 1)],
+}
+# (batch, m, n, p, sign-magnitude digits)
+_PAIR_SHAPES = {
+    "unbatched": ((), 40, 256, 128, False),
+    "batched": ((12, 8), 2, 16, 8, False),
+    "padded": ((), 12, 200, 1088, False),
+    "signmag_bf16": ((), 20, 300, 50, True),
+}
+
+
+@pytest.mark.parametrize("shape", list(_PAIR_SHAPES))
+@pytest.mark.parametrize("pairs", list(_PAIR_LISTS))
+def test_group_gemm_pair_lists(pairs, shape):
+    """ops.group_gemm reads each slice pair from the split's own stacks
+    and matches the int64 sum of A_s @ B_t, batched, padded and on
+    sign-magnitude digits alike."""
+    from repro.core.splitting import Split, sm_decode
+    batch, m, n, p, signmag = _PAIR_SHAPES[shape]
+    pairs = _PAIR_LISTS[pairs]
+    rng = np.random.default_rng(sum(map(ord, shape)))
+    a8 = jnp.asarray(rng.integers(-128, 128, (6,) + batch + (m, n)), jnp.int8)
+    b8 = jnp.asarray(rng.integers(-128, 128, (6,) + batch + (n, p)), jnp.int8)
+    sa = Split(a8, None, None, 7, 0, signmag=signmag)
+    sb = Split(b8, None, None, 7, 1, signmag=signmag)
+    got = np.asarray(ops.group_gemm(sa, sb, pairs), np.int64)
+    da = np.asarray(sm_decode(a8) if signmag else a8, np.int64)
+    db = np.asarray(sm_decode(b8) if signmag else b8, np.int64)
+    want = sum(da[s - 1] @ db[t - 1] for s, t in pairs)
+    assert got.shape == batch + (m, p)
+    np.testing.assert_array_equal(got, want)
 
 
 @settings(max_examples=8, deadline=None)
@@ -119,8 +161,10 @@ def test_group_gemm_no_int32_overflow_at_r_limit(rng):
     G = min(r, 8)
     a8 = jnp.full((G, 8, n), 127, jnp.int8)
     b8 = jnp.full((G, n, 8), 127, jnp.int8)
+    idx = jnp.arange(G, dtype=jnp.int32)
     got = np.asarray(raw_group_gemm(
-        ops._pad_to(a8, (1, 128, 128)), ops._pad_to(b8, (1, 128, 128)),
+        idx, idx, ops._pad_to(a8, (1, 128, 128)),
+        ops._pad_to(b8, (1, 128, 128)),
         bm=128, bp=128, bn=128, interpret=True), np.int64)[:8, :8]
     want = G * n * 127 * 127
     assert want < 2**31

@@ -229,6 +229,24 @@ def test_observed_counts_match_plan(spec, fresh_registry, operands):
         4 * (a.size + b.size)   # f32 operands, both sides split
 
 
+@pytest.mark.parametrize("spec", ["ozimmu_h-auto:df32:fused",
+                                  "ozimmu_sm_h-4:df32:fused"])
+def test_kernel_operand_copy_bytes(spec, fresh_registry, operands):
+    """The group-GEMM kernel reads int8 slice pairs in place: no digit
+    copies are made for it; sign-magnitude digits cost only their bf16
+    decode.  The int8 GEMM count stays the plan's."""
+    a, b = operands
+    cfg = ozimmu.parse_spec(spec)
+    ozimmu.ozimmu_dot_general(a, b, DN, cfg)
+    pl = plan.plan_contraction(cfg, a.shape[0], a.shape[1], b.shape[1],
+                               a=a, b=b, _record=False)
+    snap = fresh_registry.snapshot()
+    assert "emulation.kernel_operand_copy_bytes" in snap.names()
+    decode = 2 * pl.k * (a.size + b.size) if cfg.split == "sm" else 0
+    assert snap.total("emulation.kernel_operand_copy_bytes") == decode
+    assert snap.total("emulation.int8_gemms") == pl.int8_gemms
+
+
 @pytest.mark.parametrize("spec", AUTO_SPECS)
 def test_planned_counts_match_jitted(spec, fresh_registry, operands):
     """In a jitted program the static plan of each contraction is what

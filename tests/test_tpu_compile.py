@@ -14,6 +14,7 @@ where it cannot be described; nothing touches the TPU library while the
 module is imported.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,33 @@ def test_group_gemm_compiles(one_chip, signmag):
 
     _assert_kernel(_compiled_text(fn, one_chip, ((K, M, N), jnp.int8),
                                   ((K, N, P), jnp.int8)))
+
+
+def test_group_gemm_reads_pairs_in_place(one_chip):
+    """A contraction's group GEMMs read their slice pairs straight from
+    the split's digit stacks: ahead of the kernels the lowered program
+    has no gather and no transpose, and at most one pad per operand for
+    all six anti-diagonal groups (m = 12 and p = 1088 need padding).
+    The kernel keeps its instruction name, which the trace reader
+    finds it by."""
+    beta = splitting.compute_beta(N)
+    chunks = [[(s, g - s) for s in range(1, g)] for g in range(2, K + 2)]
+
+    def fn(da, db):
+        gemm = ops.group_gemm_fn(_split(da, beta, 0), _split(db, beta, 1))
+        return [gemm(pairs) for pairs in chunks]
+
+    args = [jax.ShapeDtypeStruct(s, jnp.int8, sharding=one_chip)
+            for s in ((K, 12, N), (K, N, 1088))]
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == len(chunks)
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.transpose" not in text
+    assert text.count("stablehlo.pad") <= 2
+    compiled = lowered.compile().as_text()
+    assert len(re.findall(r"%group_gemm\.\d+ = \S+ custom-call",
+                          compiled)) == len(chunks)
 
 
 def test_scale_accum_compiles(one_chip):
