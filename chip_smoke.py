@@ -30,8 +30,9 @@ precision off (so a ``:fused`` model and its XLA twin round their bf16
 activations alike) and turns the persistent compile cache on.
 
 ``--four-chips`` runs only the mesh phase: the same GEMM as
-``ozimmu_h-auto:df32@model/int32`` on a ``model=4`` mesh, bitwise against
-the one-device emulation, and ``serve.main --mesh model=4`` on
+``ozimmu_h-auto:df32@model/int32`` on a ``model=4`` mesh, whose C comes
+back row-sharded and is gathered to the host for the bitwise comparison
+against the one-device emulation, and ``serve.main --mesh model=4`` on
 ``ozimmu_h-auto:df32@model``, token for token against one device.
 
 The exit code is the verdict.  The last line of stdout is one JSON object

@@ -35,15 +35,18 @@ High-precision accumulator modes:
 
 Return contract (the distributed hooks):
 
-Both matmuls take two optional hooks for mesh-sharded contractions
+The matmuls take optional hooks for mesh-sharded contractions
 (see repro/distributed/collectives.py and docs/distributed.md):
 
-  * ``product_reduce`` — applied ONCE to the stacked ``(G, *batch, m, p)``
-    INT32 tensor of every slice/group product *before* any conversion or
-    scaling.  With an exact int32 ``psum`` over the mesh axis this makes a
-    contraction-sharded evaluation bit-identical to the unsharded one
-    (integer addition is associative; the overflow bound is the global-n
-    bound).  Identity when None.
+  * ``product_reduce`` — applied to EACH ``(*batch, m, p)`` INT32
+    slice/group product as soon as it is made, *before* any conversion or
+    scaling; no stack of all products is built.  With an exact int32 sum
+    over the mesh axis this makes a contraction-sharded evaluation
+    bit-identical to the unsharded one (integer addition is associative;
+    the overflow bound is the global-n bound).  A reduce-scatter returns
+    only this device's block of rows; ``row_select`` then picks the same
+    rows of A's per-row scales, and the epilogue and the result cover
+    those rows alone.  Identity when None.
   * ``partial=True`` — return the UNROUNDED accumulator instead of an
     array in ``out_dtype``: a :class:`DF32` (hi, lo) pair for
     ``accum="df32"``, the raw f64/f32 accumulator otherwise.  The caller
@@ -217,25 +220,29 @@ def num_highprec_adds(k: int, r: int, group_ef: bool) -> int:
 # Alg. 4 — naive accumulation
 # ---------------------------------------------------------------------------
 
-def _reduce_products(prods, product_reduce: Optional[Callable]):
-    """Apply ``product_reduce`` once to the stacked INT32 products.
-
-    Stacking turns the per-product reductions into ONE collective for the
-    whole GEMM; without a hook the list passes through untouched (no stack
-    materialized on the default path).
-    """
+def _reduced(prod: jax.Array, product_reduce: Optional[Callable]
+             ) -> jax.Array:
+    """One INT32 product through ``product_reduce`` (as it is made)."""
     if product_reduce is None:
-        return prods
-    reduced = product_reduce(jnp.stack(prods))
+        return prod
+    with _tracing.phase_scope("product_reduce"):
+        reduced = product_reduce(prod)
     if reduced.dtype != jnp.int32:
         raise TypeError(f"product_reduce must preserve int32 exactness, "
                         f"returned {reduced.dtype}")
-    return [reduced[i] for i in range(len(prods))]
+    return reduced
+
+
+def _rows(row_select: Optional[Callable], v: jax.Array) -> jax.Array:
+    """The rows of A's per-row vector ``v`` (trailing axis) that the
+    reduced products cover: all of them unless ``row_select`` is given."""
+    return v if row_select is None else row_select(v)
 
 
 def matmul_naive(sa: Split, sb: Split, *, accum: str = "f64",
                  out_dtype=None, partial: bool = False,
                  product_reduce: Optional[Callable] = None,
+                 row_select: Optional[Callable] = None,
                  scale_accum_fn: Optional[Callable] = None,
                  pair_gemm_fn: Optional[Callable] = None
                  ) -> Union[jax.Array, DF32]:
@@ -245,35 +252,39 @@ def matmul_naive(sa: Split, sb: Split, *, accum: str = "f64",
     every slice-pair product is then ONE batched int8 ``dot_general``.
     ``pair_gemm_fn(s, t) -> int32`` overrides the per-pair GEMM (1-indexed
     slice pair; the Pallas hook of ``use_pallas``).  ``partial`` /
-    ``product_reduce`` / ``scale_accum_fn``: see the module docstring.
+    ``product_reduce`` / ``row_select`` / ``scale_accum_fn``: see the
+    module docstring.
     """
     assert sa.axis == 0 and sb.axis == 1, "A needs row scales, B column scales"
     k = sa.digits.shape[0]
     assert sb.digits.shape[0] == k
-    out_shape = sa.digits.shape[1:-1] + (sb.digits.shape[-1],)
+    scale_a = _rows(row_select, sa.scale)
+    out_shape = scale_a.shape[1:] + (sb.digits.shape[-1],)
     out_dtype = out_dtype or sa.scale.dtype
-    pairs = _term_pairs(k)
     gemm = pair_gemm_fn or (
         lambda s, t: int8_gemm(gemm_slice(sa, s - 1), gemm_slice(sb, t - 1)))
-    with _tracing.phase_scope("group_gemm"):
-        prods = _reduce_products([gemm(s, t) for s, t in pairs],
-                                 product_reduce)
+
+    def products():
+        for s, t in _term_pairs(k):
+            with _tracing.phase_scope("group_gemm"):
+                prod = gemm(s, t)
+            yield s, t, _reduced(prod, product_reduce)
 
     if accum == "df32":
         fn = scale_accum_fn or _scale_accum_df32
         acc = df32_zero(out_shape)
-        with _tracing.phase_scope("scale_accum"):
-            for (s, t), prod in zip(pairs, prods):
-                acc = fn(prod, sa.scale[s - 1].astype(jnp.float32),
+        for s, t, prod in products():
+            with _tracing.phase_scope("scale_accum"):
+                acc = fn(prod, scale_a[s - 1].astype(jnp.float32),
                          sb.scale[t - 1].astype(jnp.float32), acc)
         return acc if partial else acc.to_float(out_dtype)
 
     acc_dtype = {"f64": jnp.float64, "f32": jnp.float32}[accum]
     fn = scale_accum_fn or _scale_accum_plain
     c = jnp.zeros(out_shape, acc_dtype)
-    with _tracing.phase_scope("scale_accum"):
-        for (s, t), prod in zip(pairs, prods):
-            c = fn(prod, sa.scale[s - 1].astype(acc_dtype),
+    for s, t, prod in products():
+        with _tracing.phase_scope("scale_accum"):
+            c = fn(prod, scale_a[s - 1].astype(acc_dtype),
                    sb.scale[t - 1].astype(acc_dtype), c)
     return c if partial else c.astype(out_dtype)
 
@@ -306,16 +317,17 @@ def matmul_group_ef(sa: Split, sb: Split, *, accum: str = "f64",
                     out_dtype=None, r: Optional[int] = None,
                     group_gemm_fn=None, partial: bool = False,
                     product_reduce: Optional[Callable] = None,
+                    row_select: Optional[Callable] = None,
                     scale_accum_fn: Optional[Callable] = None
                     ) -> Union[jax.Array, DF32]:
     """Group-wise error-free accumulation (Alg. 6; Alg. 7 when r >= k).
 
     Requires geometric slice scales (``base`` present): the combined scale of
     every pair in group g is ``baseA (x) baseB * 2^(-beta*g)``.
-    ``partial`` / ``product_reduce``: see the module docstring — when the
-    contraction axis is sharded, pass ``r`` computed from the GLOBAL
-    contraction length so the per-group INT32 partials stay summable
-    without overflow across devices.
+    ``partial`` / ``product_reduce`` / ``row_select``: see the module
+    docstring — when the contraction axis is sharded, pass ``r`` computed
+    from the GLOBAL contraction length so the per-group INT32 partials
+    stay summable without overflow across devices.
     """
     assert sa.axis == 0 and sb.axis == 1
     if sa.base is None or sb.base is None:
@@ -324,25 +336,28 @@ def matmul_group_ef(sa: Split, sb: Split, *, accum: str = "f64",
     k = sa.digits.shape[0]
     beta = sa.beta
     n = sa.digits.shape[-1]
-    out_shape = sa.digits.shape[1:-1] + (sb.digits.shape[-1],)
+    base_a = _rows(row_select, sa.base)
+    out_shape = base_a.shape + (sb.digits.shape[-1],)
     out_dtype = out_dtype or sa.scale.dtype
     if r is None:
         r = compute_r(n, beta)
     gg = group_gemm_fn or (lambda pairs: group_gemm_concat(sa, sb, pairs))
-    chunks = list(_group_chunks(k, r))
-    with _tracing.phase_scope("group_gemm"):
-        prods = _reduce_products([gg(pairs) for _, pairs in chunks],
-                                 product_reduce)
+
+    def products():
+        for g, pairs in _group_chunks(k, r):
+            with _tracing.phase_scope("group_gemm"):
+                prod = gg(pairs)
+            yield g, _reduced(prod, product_reduce)
 
     # The 2^(-beta*g) group exponent folds into the row scale (exact:
     # powers of two), matching the fused kernel's srow contract.
     if accum == "df32":
         fn = scale_accum_fn or _scale_accum_df32
         acc = df32_zero(out_shape)
-        base_a = sa.base.astype(jnp.float32)
+        base_a = base_a.astype(jnp.float32)
         base_b = sb.base.astype(jnp.float32)
-        with _tracing.phase_scope("scale_accum"):
-            for (g, _), prod in zip(chunks, prods):
+        for g, prod in products():
+            with _tracing.phase_scope("scale_accum"):
                 e = jnp.asarray(2.0 ** (-beta * g), jnp.float32)
                 acc = fn(prod, base_a * e, base_b, acc)
         return acc if partial else acc.to_float(out_dtype)
@@ -350,10 +365,10 @@ def matmul_group_ef(sa: Split, sb: Split, *, accum: str = "f64",
     acc_dtype = {"f64": jnp.float64, "f32": jnp.float32}[accum]
     fn = scale_accum_fn or _scale_accum_plain
     c = jnp.zeros(out_shape, acc_dtype)
-    base_a = sa.base.astype(acc_dtype)
+    base_a = base_a.astype(acc_dtype)
     base_b = sb.base.astype(acc_dtype)
-    with _tracing.phase_scope("scale_accum"):
-        for (g, _), prod in zip(chunks, prods):
+    for g, prod in products():
+        with _tracing.phase_scope("scale_accum"):
             e = jnp.asarray(2.0 ** (-beta * g), acc_dtype)
             c = fn(prod, base_a * e, base_b, c)
     return c if partial else c.astype(out_dtype)
@@ -495,6 +510,7 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
                digit_bits: Optional[int] = None, group_gemm_fn=None,
                partial: bool = False,
                product_reduce: Optional[Callable] = None,
+               row_select: Optional[Callable] = None,
                scale_accum_fn: Optional[Callable] = None,
                unscale_fn: Optional[Callable] = None
                ) -> Union[jax.Array, DF32]:
@@ -523,9 +539,10 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
     ra, rb)`` overrides that epilogue (the fused Pallas hook
     ``repro.kernels.ops.oz2_unscale_update``; bit-identical).
 
-    ``partial`` / ``product_reduce`` follow the module contract: the
-    product psum applies to the stacked int32 chunk products BEFORE the
-    ladder fold, so the int32 mesh strategy stays bit-identical.
+    ``partial`` / ``product_reduce`` / ``row_select`` follow the module
+    contract: the cross-device sum applies to each int32 chunk product
+    BEFORE the ladder fold, so the int32 mesh strategy stays
+    bit-identical.
     ``scale_accum_fn(word, scale, acc)`` is the oz2 fused-epilogue hook
     (``repro.kernels.ops.oz2_scale_accum_update``): ``word`` the folded
     int32/int64 window, ``scale`` the ``(*batch,)`` scalar power of two.
@@ -547,7 +564,7 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
     assert sb.digits.shape[0] == k
     beta = sa.beta
     n = n_total if n_total is not None else sa.digits.shape[-1]
-    out_shape = sa.digits.shape[1:-1] + (sb.digits.shape[-1],)
+    out_shape = _rows(row_select, sa.scale[0]).shape + (sb.digits.shape[-1],)
     out_dtype = out_dtype or sa.scale.dtype
     if digit_bits is None:
         digit_bits = beta  # conservative: truncation digits span ±(2^beta-1)
@@ -560,19 +577,22 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
 
     gg = group_gemm_fn or (lambda pairs: group_gemm_concat(sa, sb, pairs))
     chunks = list(_oz2_chunks(k, r, fast))
-    with _tracing.phase_scope("group_gemm"):
-        prods = _reduce_products([gg(pairs) for _, pairs in chunks],
-                                 product_reduce)
     windows = _ladder_windows(chunks, c)
 
     def fold(window):
+        """The window's chunk products, each reduced as it is made, folded
+        into one integer word."""
         g_hi = window[-1][1]
         word = None
         for idx, g in window:
-            t = prods[idx].astype(word_dtype)
-            if g_hi != g:
-                t = jnp.left_shift(t, beta * (g_hi - g))
-            word = t if word is None else word + t
+            with _tracing.phase_scope("group_gemm"):
+                prod = gg(chunks[idx][1])
+            t = _reduced(prod, product_reduce)
+            with _tracing.phase_scope("ladder"):
+                t = t.astype(word_dtype)
+                if g_hi != g:
+                    t = jnp.left_shift(t, beta * (g_hi - g))
+                word = t if word is None else word + t
         return word, g_hi
 
     def unscale(acc):
@@ -580,7 +600,7 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
         power-of-two unscale by the equilibration factors base/gbase."""
         if not fast2:
             return acc
-        ra = sa.base * (1.0 / sa.gbase[..., None])
+        ra = _rows(row_select, sa.base) * (1.0 / sa.gbase[..., None])
         rb = sb.base * (1.0 / sb.gbase[..., None])
         return (unscale_fn or _oz2_unscale)(acc, ra, rb)
 
@@ -588,8 +608,7 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
         fn = scale_accum_fn or _oz2_accum_df32
         acc = df32_zero(out_shape)
         for window in windows:
-            with _tracing.phase_scope("ladder"):
-                word, g_hi = fold(window)
+            word, g_hi = fold(window)
             with _tracing.phase_scope("scale_accum"):
                 acc = fn(word, _oz2_scale(sa.gbase, sb.gbase, beta, g_hi,
                                           jnp.float32), acc)
@@ -601,8 +620,7 @@ def matmul_oz2(sa: Split, sb: Split, *, accum: str = "f64",
     fn = scale_accum_fn or _oz2_accum_plain
     acc = jnp.zeros(out_shape, acc_dtype)
     for window in windows:
-        with _tracing.phase_scope("ladder"):
-            word, g_hi = fold(window)
+        word, g_hi = fold(window)
         with _tracing.phase_scope("scale_accum"):
             acc = fn(word, _oz2_scale(sa.gbase, sb.gbase, beta, g_hi,
                                       acc_dtype), acc)

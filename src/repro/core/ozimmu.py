@@ -59,7 +59,8 @@ Mesh-native mode (``OzimmuConfig.mesh_axis`` / spec suffix ``@model``):
 when a mesh is installed and the contraction length divides the named
 axis, the contraction runs sharded under ``shard_map`` with the
 cross-device accumulation kept inside the scheme — an exact INT32
-product ``psum`` (bit-identical to the unsharded emulation) or, with
+reduce-scatter of each group's product that leaves C row-sharded
+(bit-identical to the unsharded emulation) or, with
 ``mesh_reduce="df32"``, a TwoSum-compensated reduction of the partial
 accumulators with one final rounding.  See docs/distributed.md.
 """
@@ -196,15 +197,15 @@ def variant_name(cfg: "OzimmuConfig") -> str:
 
 
 def _record_emulation(cfg: "OzimmuConfig", a, p: int,
-                      presplit: bool, sharded: bool,
+                      presplit: bool, devices: int, scatter: bool,
                       spec_cfg: "OzimmuConfig") -> None:
     """Mirror one resolved contraction into the metrics registry.
 
     Called from ``_bmm_impl`` after the config is fully canonical (fast2
     tied, accumulator downgraded, auto-k resolved to a concrete k), so
     the recorded counts are exactly what executes; the ``mesh`` label
-    names the mesh axis only when the sharded path runs (``sharded``),
-    so a mesh-native spec that fell back to the local path shows
+    names the mesh axis only when the sharded path runs over ``devices``
+    > 1, so a mesh-native spec that fell back to the local path shows
     ``mesh="none"``.  Host-side only: runs
     once per eager call or per jit *trace* — a compiled step that traced
     through here replays the same contraction on every execution, so
@@ -226,6 +227,12 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
     digit copies the group-GEMM wrapper makes for its kernel
     (``kernels.ops.operand_copy_bytes``): 0 for int8 digits, whose slice
     pairs the kernel reads in place; the bf16 decode for sign-magnitude.
+
+    ``emulation.product_reduce_bytes`` counts the int32 bytes one device
+    sends into the exact cross-device reduction of the slice/group
+    products (``mesh_reduce="int32"``): (D-1)/D of every product under
+    the reduce-scatter (``scatter``), twice that under a ``psum``; 0 on
+    one device and under the df32 strategy, which reduces accumulators.
     """
     from repro.core import plan as _plan
     m, n = a.shape[-2], a.shape[-1]
@@ -237,7 +244,7 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
         variant=variant_name(cfg), k=cfg.k,
         path=("fused" if cfg.use_pallas == "fused"
               else "pallas" if cfg.use_pallas else "xla"),
-        mesh=cfg.mesh_axis if sharded else "none",
+        mesh=cfg.mesh_axis if devices > 1 else "none",
         presplit=int(presplit))
     reg = _obs.get_registry()
     # inside the trace of a scan body, one trace stands for every step
@@ -248,6 +255,13 @@ def _record_emulation(cfg: "OzimmuConfig", a, p: int,
             **labels)
     reg.inc("emulation.highprec_adds", r * batch * pl.highprec_adds,
             **labels)
+    sent = 0
+    if devices > 1 and cfg.mesh_reduce == "int32":
+        products = (accumulate.oz2_num_chunks(cfg.k, pl.r, cfg.fast)
+                    if cfg.accumulate == "oz2" else pl.highprec_adds)
+        sent = ((1 if scatter else 2) * (devices - 1) * products * batch
+                * m * p * 4 // devices)
+    reg.inc("emulation.product_reduce_bytes", r * sent, **labels)
     itemsize = np.dtype(a.dtype).itemsize
     split_elems = batch * m * n            # A is always split in-call;
     if not presplit:                       # B only when no frozen Split
@@ -390,23 +404,24 @@ def split_operands(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, *,
     """
     n = n_total if n_total is not None else a.shape[-1]
     beta = splitting.beta_for(cfg.split, n)
+    sa = _split_side(a, cfg, beta, 0, rowmax_reduce)
+    if rhs_presplit is not None:
+        return sa, rhs_presplit
+    return sa, _split_side(b, cfg, beta, 1, rowmax_reduce)
+
+
+def _split_side(x: jax.Array, cfg: OzimmuConfig, beta: int, axis: int,
+                rowmax_reduce=None) -> splitting.Split:
+    """One operand of :func:`split_operands`: A row-wise (``axis=0``), B
+    column-wise (``axis=1``)."""
     if cfg.use_pallas == "fused" and cfg.split != "rn":
         # every constant-ratio strategy fuses: per-row grids (bitmask,
         # rn_const) and the oz2 shared constant grids alike
         from repro.kernels import ops as kops  # lazy: kernels are optional
-        sa = kops.split_fused(a, cfg.k, beta, mode=cfg.split, axis=0,
-                              rowmax_reduce=rowmax_reduce)
-        if rhs_presplit is not None:
-            return sa, rhs_presplit
-        sb = kops.split_fused(b, cfg.k, beta, mode=cfg.split, axis=1,
-                              rowmax_reduce=rowmax_reduce)
-        return sa, sb
-    splitter = _SPLITTERS[cfg.split]
-    sa = splitter(a, cfg.k, beta=beta, axis=0, rowmax_reduce=rowmax_reduce)
-    if rhs_presplit is not None:
-        return sa, rhs_presplit
-    sb = splitter(b, cfg.k, beta=beta, axis=1, rowmax_reduce=rowmax_reduce)
-    return sa, sb
+        return kops.split_fused(x, cfg.k, beta, mode=cfg.split, axis=axis,
+                                rowmax_reduce=rowmax_reduce)
+    return _SPLITTERS[cfg.split](x, cfg.k, beta=beta, axis=axis,
+                                 rowmax_reduce=rowmax_reduce)
 
 
 def _bmm_local(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, *,
@@ -428,6 +443,16 @@ def _bmm_local(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, *,
         sa, sb = split_operands(a, b, cfg, n_total=n_total,
                                 rowmax_reduce=rowmax_reduce,
                                 rhs_presplit=rhs_presplit)
+    return _contract(sa, sb, cfg, a.dtype, n_total=n_total,
+                     product_reduce=product_reduce, partial=partial)
+
+
+def _contract(sa: splitting.Split, sb: splitting.Split, cfg: OzimmuConfig,
+              out_dtype, *, n_total: Optional[int] = None,
+              product_reduce=None, row_select=None, partial: bool = False):
+    """Steps (iii)+(iv) on split operands: the int8 slice-pair GEMMs and
+    their high-precision accumulation (``accumulate``'s hooks
+    ``product_reduce``, ``row_select`` and ``partial`` passed through)."""
     group_gemm_fn = scale_accum_fn = pair_gemm_fn = unscale_fn = None
     if cfg.use_pallas:
         from repro.kernels import ops as kops  # lazy: kernels are optional
@@ -444,35 +469,77 @@ def _bmm_local(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, *,
                               if cfg.accumulate == "oz2"
                               else kops.scale_accum_update)
             unscale_fn = kops.oz2_unscale_update
+    hooks = dict(accum=cfg.accum_dtype, out_dtype=out_dtype, partial=partial,
+                 product_reduce=product_reduce, row_select=row_select,
+                 scale_accum_fn=scale_accum_fn)
     if cfg.accumulate == "naive":
-        return accumulate.matmul_naive(
-            sa, sb, accum=cfg.accum_dtype, out_dtype=a.dtype,
-            partial=partial, product_reduce=product_reduce,
-            scale_accum_fn=scale_accum_fn, pair_gemm_fn=pair_gemm_fn)
-    n = n_total if n_total is not None else a.shape[-1]
+        return accumulate.matmul_naive(sa, sb, pair_gemm_fn=pair_gemm_fn,
+                                       **hooks)
+    n = n_total if n_total is not None else sa.digits.shape[-1]
     if cfg.accumulate == "oz2":
         return accumulate.matmul_oz2(
-            sa, sb, accum=cfg.accum_dtype, out_dtype=a.dtype,
-            fast=cfg.fast, n_total=n, digit_bits=digit_bits(cfg, sa.beta),
-            group_gemm_fn=group_gemm_fn, partial=partial,
-            product_reduce=product_reduce, scale_accum_fn=scale_accum_fn,
-            unscale_fn=unscale_fn)
+            sa, sb, fast=cfg.fast, n_total=n,
+            digit_bits=digit_bits(cfg, sa.beta), group_gemm_fn=group_gemm_fn,
+            unscale_fn=unscale_fn, **hooks)
     r = splitting.compute_r(n, sa.beta)
-    return accumulate.matmul_group_ef(
-        sa, sb, accum=cfg.accum_dtype, out_dtype=a.dtype, r=r,
-        group_gemm_fn=group_gemm_fn, partial=partial,
-        product_reduce=product_reduce, scale_accum_fn=scale_accum_fn)
+    return accumulate.matmul_group_ef(sa, sb, r=r,
+                                      group_gemm_fn=group_gemm_fn, **hooks)
+
+
+def _scatters(cfg: OzimmuConfig, m: int, devices: int,
+              presplit: bool) -> bool:
+    """Whether the sharded path reduces each int32 product by a
+    reduce-scatter over C's ``m`` rows, leaving C row-sharded: under the
+    int32 strategy, when the rows divide the axis and B is split in-call.
+    Otherwise (and for the frozen B of serving) a ``psum`` leaves the whole
+    C on every device."""
+    return (cfg.mesh_reduce == "int32" and not presplit
+            and m % devices == 0)
+
+
+# C's columns are contracted in blocks only where each block is a whole
+# number of the kernels' 128-lane column tiles
+_LANES = 128
+
+# Splits whose digit grid is one power of two for the whole matrix
+# (``splitting._global_base``): a block of B's columns split on its own
+# would get a grid of its own, so B is contracted whole.
+_WHOLE_MATRIX_GRIDS = ("oz2_rn", "oz2_bitmask")
+
+
+def _column_blocks(cfg: OzimmuConfig, p: int, devices: int) -> int:
+    """How many column blocks of C the reduce-scatter path contracts one
+    after another: ``devices`` where each is whole lane tiles and B's
+    digit grid is per column, else 1.  A block's int32 group partial,
+    (m, p/devices) on each device, is then no larger than the device's
+    own rows of C; the whole (m, p) partial of a large product would not
+    fit beside the operands and their digits."""
+    if cfg.split in _WHOLE_MATRIX_GRIDS or p % (devices * _LANES):
+        return 1
+    return devices
 
 
 @functools.lru_cache(maxsize=256)
-def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
-                out_dtype, presplit_meta=None) -> "callable":
-    """The jitted shard_map callable for one (config, mesh, rank) cell.
+def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, m: int, n_total: int,
+                p: int, out_dtype, presplit_meta=None) -> "callable":
+    """The jitted shard_map callable for one (config, mesh, shape) cell.
 
     Cached so repeated *eager* mesh-native contractions reuse one
     compiled entry instead of re-wrapping a fresh closure in ``jax.jit``
     per call (which would defeat jit's own cache).  Inside an outer jit
     it inlines for free.
+
+    Under the int32 strategy each group's int32 partial product is
+    reduced as soon as it exists, by one reduce-scatter over C's rows
+    (``_scatters``): each device ends with the exact sum of its own m/D
+    rows, runs the high-precision epilogue on those rows alone, and C
+    leaves the shard_map row-sharded, ``P(axis, None)``; a caller that
+    needs C replicated gets it by an all-gather from jit/GSPMD.  B's
+    columns are split and contracted in ``_column_blocks`` blocks, one
+    after another, so that no whole (m, p) partial is held where B's
+    digit grid is per column.  Where the
+    rows do not divide the axis, or B is frozen, each product is
+    ``psum``'d instead and C is replicated.
 
     ``presplit_meta`` (serving): ``(beta, has_base, has_gbase, signmag)``
     of a frozen B-side Split — the callable then takes ``(a, (digits,
@@ -487,9 +554,13 @@ def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
     from repro.distributed import collectives
 
     axis = cfg.mesh_axis
+    devices = dict(mesh.shape)[axis]
+    scatter = _scatters(cfg, m, devices, presplit_meta is not None)
     a_spec = P(*((None,) * (nb + 1) + (axis,)))
-    out_specs = P(*((None,) * (nb + 2)))
+    out_specs = P(*((None,) * nb + ((axis, None) if scatter
+                                    else (None, None))))
     local_cfg = cfg.local()
+    rowmax_reduce = lambda v: collectives.pmax_scales(v, axis)
 
     if presplit_meta is None:
         in_specs = (a_spec, P(*((None,) * nb + (axis, None))))
@@ -507,14 +578,37 @@ def _sharded_fn(cfg: OzimmuConfig, mesh, nb: int, n_total: int,
             return None, splitting.Split(digits, scale, base, beta, 1,
                                          gbase=gbase, signmag=signmag)
 
-    if cfg.mesh_reduce == "int32":
+    if scatter:
+        rows = m // devices
+        blocks = _column_blocks(local_cfg, p, devices)
+        width = p // blocks
+        beta = splitting.beta_for(cfg.split, n_total)
+
+        def body(al, bl):
+            first = jax.lax.axis_index(axis) * rows
+            hooks = dict(
+                n_total=n_total,
+                product_reduce=lambda q: collectives.psum_exact_int32(
+                    q, axis, scatter_dimension=nb),
+                row_select=lambda v: jax.lax.dynamic_slice_in_dim(
+                    v, first, rows, axis=-1))
+            with _tracing.phase_scope("split"):
+                sa = _split_side(al, local_cfg, beta, 0, rowmax_reduce)
+            out = []
+            for c in range(blocks):
+                with _tracing.phase_scope("split"):
+                    sb = _split_side(bl[..., c * width:(c + 1) * width],
+                                     local_cfg, beta, 1, rowmax_reduce)
+                out.append(_contract(sa, sb, local_cfg, al.dtype, **hooks))
+            return jnp.concatenate(out, axis=-1) if blocks > 1 else out[0]
+    elif cfg.mesh_reduce == "int32":
         def body(al, operand):
             bl, sb = unpack(operand)
             return _bmm_local(
                 al, bl, local_cfg, n_total=n_total,
-                rowmax_reduce=lambda v: collectives.pmax_scales(v, axis),
-                product_reduce=lambda p: collectives.psum_exact_int32(
-                    p, axis),
+                rowmax_reduce=rowmax_reduce,
+                product_reduce=lambda q: collectives.psum_exact_int32(
+                    q, axis),
                 rhs_presplit=sb)
     else:
         def body(al, operand):
@@ -536,14 +630,16 @@ def _bmm_sharded(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, mesh,
     ``cfg.mesh_axis``, cross-device accumulation inside the scheme.
 
     Strategy ``int32`` (default): row/col maxima are agreed across shards
-    (one ``pmax``), every INT32 slice/group product is summed exactly over
-    the axis (one stacked ``psum``), and the high-precision accumulation
-    runs on the already-global products — bit-identical to the unsharded
-    emulation.  Strategy ``df32``: each shard accumulates its local partial
-    (local scales, no pmax pre-pass), and the partial accumulators are
-    merged with a TwoSum-compensated reduction — one all-gather for the
-    whole GEMM, error-free in the two-float representation, with the single
-    final rounding after the merge.
+    (one ``pmax``), each INT32 slice/group product is summed exactly over
+    the axis as soon as it exists (one reduce-scatter over C's rows per
+    group, C returned row-sharded; a ``psum`` per group where the rows do
+    not divide the axis or B is frozen), and the high-precision
+    accumulation runs on the already-global products — bit-identical to
+    the unsharded emulation.  Strategy ``df32``: each shard accumulates
+    its local partial (local scales, no pmax pre-pass), and the partial
+    accumulators are merged with a TwoSum-compensated reduction — one
+    all-gather for the whole GEMM, error-free in the two-float
+    representation, with the single final rounding after the merge.
 
     With ``rhs_presplit`` the cached B digits enter the shard_map sharded
     along their contraction axis; bit-identity with the unsharded presplit
@@ -554,12 +650,13 @@ def _bmm_sharded(a: jax.Array, b: jax.Array, cfg: OzimmuConfig, mesh,
     compensated merge semantics are unchanged.
     """
     nb = a.ndim - 2
+    m, n, p = a.shape[-2], a.shape[-1], b.shape[-1]
     if rhs_presplit is None:
-        return _sharded_fn(cfg, mesh, nb, a.shape[-1], a.dtype)(a, b)
+        return _sharded_fn(cfg, mesh, nb, m, n, p, a.dtype)(a, b)
     sp = rhs_presplit
     meta = (int(sp.beta), sp.base is not None, sp.gbase is not None,
             bool(sp.signmag))
-    fn = _sharded_fn(cfg, mesh, nb, a.shape[-1], a.dtype, meta)
+    fn = _sharded_fn(cfg, mesh, nb, m, n, p, a.dtype, meta)
     return fn(a, (sp.digits, sp.scale, sp.base, sp.gbase))
 
 
@@ -653,8 +750,11 @@ def _bmm_impl(a: jax.Array, b: jax.Array, cfg: OzimmuConfig,
         _check_presplit(a, b.shape, cfg, rhs_presplit)
     mesh = _mesh_for(cfg, a.shape[-1])
     if _obs.enabled():
-        _record_emulation(cfg, a, b.shape[-1], rhs_presplit is not None,
-                          sharded=mesh is not None, spec_cfg=spec_cfg)
+        presplit = rhs_presplit is not None
+        devices = dict(mesh.shape)[cfg.mesh_axis] if mesh is not None else 1
+        _record_emulation(cfg, a, b.shape[-1], presplit, devices,
+                          _scatters(cfg, a.shape[-2], devices, presplit),
+                          spec_cfg=spec_cfg)
     if mesh is not None:
         return _bmm_sharded(a, b, cfg, mesh, rhs_presplit)
     return _bmm_local(a, b, cfg.local(), rhs_presplit=rhs_presplit)

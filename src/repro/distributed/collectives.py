@@ -8,8 +8,10 @@ the scheme just paid for (the reduction rounds at 2^-24 while the
 accumulator carries ~2^-48 or better).  This module provides the two
 reductions that keep the scheme's invariants (see docs/distributed.md):
 
-  * :func:`psum_exact_int32` — sum INT32 slice/group partials across
-    devices *before* any float conversion.  Bit-exact: each device's
+  * :func:`psum_exact_int32` — sum each INT32 slice/group partial across
+    devices *before* any float conversion, as a reduce-scatter over C's
+    rows (each device keeps the sum of its own rows) or, where the rows
+    do not divide the axis, a ``psum``.  Bit-exact: each device's
     partial over its n_i local contraction columns is bounded by
     ``n_i * (2^beta - 1)^2`` and the partials sum to the unsharded product,
     so every intermediate stays under the same ``n * (2^beta - 1)^2 < 2^31``
@@ -30,6 +32,8 @@ deterministic and identical on every device — the device index, not the
 reduction topology, orders the fold.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,20 +56,27 @@ def pmax_scales(v: jax.Array, axis_name: str) -> jax.Array:
     return lax.pmax(v, axis_name)
 
 
-def psum_exact_int32(p: jax.Array, axis_name: str) -> jax.Array:
-    """Exact cross-device sum of INT32 partial slice/group products.
+def psum_exact_int32(p: jax.Array, axis_name: str,
+                     scatter_dimension: Optional[int] = None) -> jax.Array:
+    """Exact cross-device sum of one INT32 partial slice/group product.
 
-    ``p`` may be a single product or a stacked ``(G, *batch, m, p)`` tensor
-    of all products of a GEMM (one collective for the whole scheme).  The
-    no-overflow argument requires that the *global* contraction length was
-    used for beta (eq. 4) and r (eq. 12) — the sharded engine path does
-    this — so the sum of partials equals the unsharded INT32 product
-    bit for bit.
+    The sharded engine path calls it once per group, as soon as the
+    group's product exists, so the ``(G, *batch, m, p)`` stack of all
+    products is never built.  With ``scatter_dimension`` (C's row axis)
+    it is a reduce-scatter: each device receives the sum of its own block
+    of rows, and C stays row-sharded; without it, a ``psum`` that leaves
+    the whole sum on every device.  The no-overflow argument requires
+    that the *global* contraction length was used for beta (eq. 4) and r
+    (eq. 12) — the sharded engine path does this — so the sum of
+    partials equals the unsharded INT32 product bit for bit.
     """
     if p.dtype != jnp.int32:
         raise TypeError(f"psum_exact_int32 needs int32 partials, got "
                         f"{p.dtype}; float partials lose exactness")
-    return lax.psum(p, axis_name)
+    if scatter_dimension is None:
+        return lax.psum(p, axis_name)
+    return lax.psum_scatter(p, axis_name, scatter_dimension=scatter_dimension,
+                            tiled=True)
 
 
 def psum_df32(c: DF32, axis_name: str) -> DF32:

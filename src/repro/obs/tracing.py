@@ -4,12 +4,12 @@ Two scope flavors, both no-ops when the obs layer is disabled:
 
 * :func:`phase_scope` — ``jax.named_scope``: attaches a name to the ops
   staged under it, so xprof/Perfetto shows ``ozimmu/split``,
-  ``ozimmu/group_gemm``, ``ozimmu/ladder``, ``ozimmu/scale_accum``
-  blocks inside the compiled program.  Pure metadata: the lowered HLO
-  computes the same values in the same order, which keeps the bitwise
-  contract (tests assert identity with obs on vs off).  Works for both
-  the XLA path and the fused Pallas pipeline — the kernel calls are
-  staged under the same scopes.
+  ``ozimmu/group_gemm``, ``ozimmu/product_reduce``, ``ozimmu/ladder``,
+  ``ozimmu/scale_accum`` blocks inside the compiled program.  Pure
+  metadata: the lowered HLO computes the same values in the same order,
+  which keeps the bitwise contract (tests assert identity with obs on vs
+  off).  Works for both the XLA path and the fused Pallas pipeline —
+  the kernel calls are staged under the same scopes.
 * :func:`span` — ``jax.profiler.TraceAnnotation``: brackets *host* work
   (the serving runtime's round and its phases, ``serve/*``) on the host
   plane of the profiler's trace, on the device timeline's clock.  Integer
@@ -37,7 +37,7 @@ __all__ = ["phase_scope", "span", "profile", "PHASES"]
 
 # The emulation pipeline's phase names (docs/observability.md): every
 # phase_scope is ozimmu/<one of these>.
-PHASES = ("split", "group_gemm", "ladder", "scale_accum")
+PHASES = ("split", "group_gemm", "product_reduce", "ladder", "scale_accum")
 
 _NULL = contextlib.nullcontext()
 

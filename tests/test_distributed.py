@@ -17,9 +17,9 @@ import pytest
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def run_sub(code: str, timeout=900, x64=False):
+def run_sub(code: str, timeout=900, x64=False, devices=8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = _SRC
     env.pop("JAX_ENABLE_X64", None)
     if x64:  # must be set before jax initializes in the subprocess
@@ -397,13 +397,22 @@ def test_ozimmu_sharded_vjp_bitwise():
     """)
 
 
-def test_ozimmu_sharded_fused_pipeline_bitwise():
+@pytest.mark.parametrize("devices,m,p", [
+    (8, 48, 64),        # 6 rows a device, one column block
+    (4, 48, 512),       # 12 rows a device, four 128-column blocks
+    (4, 30, 64),        # rows do not divide the axis: psum, C replicated
+], ids=["8dev", "4dev_column_blocks", "4dev_rows_indivisible"])
+def test_ozimmu_sharded_fused_pipeline_bitwise(devices, m, p):
     """The fused Pallas pipeline (``:fused``) composes with the mesh-native
     path: under the exact-int32 reduction the sharded fused emulation is
     bit-identical to the single-device fused AND unfused paths, for all
-    four variants (the acceptance invariant of the fused pipeline)."""
-    run_sub("""
+    four variants (the acceptance invariant of the fused pipeline).  Where
+    C's rows divide the axis each group's product is reduce-scattered and
+    C comes back row-sharded, ``P("model", None)``; otherwise each product
+    is psum'd and C comes back replicated."""
+    run_sub(f"""
         import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import ozimmu
         from jax import set_mesh
         from repro.launch.mesh import make_test_mesh
@@ -413,10 +422,12 @@ def test_ozimmu_sharded_fused_pipeline_bitwise():
             u = rng.uniform(0, 1, (m, n)); z = rng.standard_normal((m, n))
             return (u - 0.5) * np.exp(phi * z)
 
-        a = jnp.asarray(phi_mat(48, 256), jnp.float32)
-        b = jnp.asarray(phi_mat(256, 64), jnp.float32)
+        m, p, devices = {m}, {p}, {devices}
+        a = jnp.asarray(phi_mat(m, 256), jnp.float32)
+        b = jnp.asarray(phi_mat(256, p), jnp.float32)
         dn = (((1,), (0,)), ((), ()))
-        mesh = make_test_mesh(data=1, model=8)
+        mesh = make_test_mesh(data=1, model=devices)
+        rows = NamedSharding(mesh, P("model", None))
         for name in ("ozimmu", "ozimmu_rn", "ozimmu_ef", "ozimmu_h",
                      "ozimmu_sm_b", "ozimmu_sm_h"):
             for accum in ("f32", "df32"):
@@ -431,20 +442,32 @@ def test_ozimmu_sharded_fused_pipeline_bitwise():
                     got = jax.jit(lambda a, b: ozimmu.ozimmu_dot_general(
                         a, b, dn, sharded))(a, b)
                 assert bool(jnp.all(fused == got)), (name, accum)
+                if m % devices == 0:
+                    assert got.sharding.is_equivalent_to(rows, 2), \
+                        got.sharding
+                else:
+                    assert got.sharding.is_fully_replicated, got.sharding
                 print(name, accum, "fused sharded bitwise OK")
         print("OK")
-    """)
+    """, devices=devices)
 
 
-def test_oz2_sharded_bitwise_both_modes():
+@pytest.mark.parametrize("devices,p", [
+    (8, 64),            # one column block
+    (4, 512),           # four 128-column blocks where B's grid allows
+], ids=["8dev", "4dev_column_blocks"])
+def test_oz2_sharded_bitwise_both_modes(devices, p):
     """Ozaki-II (constant scaling + exponent ladder): under the exact-int32
     reduction the sharded emulation — plain and fused — is bit-identical
     to the single-device path for both oz2 variants, full, fast AND fast2
     modes (the digit grid is agreed via one pmax — per-row for fast2 —
-    and the int32 chunk products are psum'd BEFORE the ladder fold; the
+    and the int32 chunk products are reduced BEFORE the ladder fold; the
     fast2 diag unscale is a pure-pow2 rescale of the reduced result, so
-    it cannot break the bitwise invariant)."""
-    run_sub("""
+    it cannot break the bitwise invariant).  With four devices and 512
+    columns, B's first 128 columns are scaled up: the full and fast
+    modes' single grid for all of B then differs from a grid of any
+    other column block alone."""
+    run_sub(f"""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ozimmu
         from jax import set_mesh
@@ -455,10 +478,14 @@ def test_oz2_sharded_bitwise_both_modes():
             u = rng.uniform(0, 1, (m, n)); z = rng.standard_normal((m, n))
             return (u - 0.5) * np.exp(phi * z)
 
+        p, devices = {p}, {devices}
         a = jnp.asarray(phi_mat(48, 256), jnp.float32)
-        b = jnp.asarray(phi_mat(256, 64), jnp.float32)
+        b = phi_mat(256, p)
+        if p > 128:
+            b[:, :128] *= 1024.0
+        b = jnp.asarray(b, jnp.float32)
         dn = (((1,), (0,)), ((), ()))
-        mesh = make_test_mesh(data=1, model=8)
+        mesh = make_test_mesh(data=1, model=devices)
         for name in ("oz2_b", "oz2_h"):
             for fast in (False, True, "fast2"):
                 for pallas in (False, "fused"):
@@ -473,10 +500,10 @@ def test_oz2_sharded_bitwise_both_modes():
                         got = jax.jit(lambda a, b: ozimmu.ozimmu_dot_general(
                             a, b, dn, cfg.with_(mesh_axis="model")))(a, b)
                     assert bool(jnp.all(ref == got)), (name, fast, pallas)
-                print(name, {False: "full", True: "fast"}.get(fast, "fast2"),
+                print(name, {{False: "full", True: "fast"}}.get(fast, "fast2"),
                       "sharded bitwise OK")
         print("OK")
-    """)
+    """, devices=devices)
 
 
 def test_oz2_fast2_sharded_int32_bitwise():

@@ -380,6 +380,58 @@ def test_bitwise_identity_mesh_int32_obs_on_off():
     assert "OK" in p.stdout
 
 
+def test_product_reduce_bytes_sharded_int32_only(fresh_registry, operands):
+    """``emulation.product_reduce_bytes`` is what one device sends into the
+    exact int32 reduction of the G group products: (D-1)/D x G x m x p x 4
+    under the reduce-scatter (rows divide the axis), twice that under the
+    psum (they do not); 0 on one device and under ``/df32``, which reduces
+    accumulators, not products (subprocess, 4 forced host devices)."""
+    a, b = operands
+    ozimmu.ozimmu_dot_general(a, b, DN, ozimmu.parse_spec("ozimmu_h-4:df32"))
+    assert "emulation.product_reduce_bytes" in fresh_registry.snapshot().names()
+    assert fresh_registry.total("emulation.product_reduce_bytes") == 0
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = src
+    code = textwrap.dedent("""
+        import numpy as np, jax, jax.numpy as jnp
+        from repro.core import ozimmu, plan
+        from repro.obs import registry
+        from jax import set_mesh
+        from repro.launch.mesh import make_test_mesh
+
+        rng = np.random.default_rng(11)
+        dn = (((1,), (0,)), ((), ()))
+        mesh = make_test_mesh(data=1, model=4)
+        reg = registry.get_registry()
+        for spec, m, per_product in (
+                ("ozimmu_h-4:df32@model/int32", 8, 3 / 4),
+                ("ozimmu_h-4:df32@model/int32", 6, 2 * 3 / 4),
+                ("oz2_h-4:df32:fast@model/int32", 8, 3 / 4),
+                ("ozimmu_h-4:df32@model/df32", 8, 0)):
+            a = jnp.asarray(rng.standard_normal((m, 256)), jnp.float32)
+            b = jnp.asarray(rng.standard_normal((256, 16)), jnp.float32)
+            cfg = ozimmu.parse_spec(spec)
+            before = reg.total("emulation.product_reduce_bytes")
+            with set_mesh(mesh):
+                jax.jit(lambda a, b: ozimmu.ozimmu_dot_general(
+                    a, b, dn, cfg))(a, b)
+            sent = reg.total("emulation.product_reduce_bytes") - before
+            pl = plan.plan_contraction(cfg, m, 256, 16, _record=False)
+            # oz2 reduces each anti-diagonal group g = 2..k+1 before its
+            # ladder fold: k = 4 groups (r covers every group at n = 256)
+            groups = 4 if cfg.accumulate == "oz2" else pl.highprec_adds
+            assert sent == per_product * groups * m * 16 * 4, (spec, m, sent)
+            print(spec, m, sent)
+        print("OK")
+    """)
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=900, env=env)
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr}"
+    assert "OK" in p.stdout
+
+
 # ---------------------------------------------------------------------------
 # planner audit ledger
 # ---------------------------------------------------------------------------

@@ -152,6 +152,40 @@ def test_fused_dot_general_compiles(one_chip):
                                   ((N, P), jnp.float32)))
 
 
+def test_sharded_int32_product_reduce_holds_no_stack(topo):
+    """``ozimmu_h-auto:df32:fused@model/int32`` over the described host's
+    four chips at 32768^3 (k = 6, 8 groups): each group's int32 product is
+    reduced by rows as soon as it exists and C comes back row-sharded, so
+    the program's temporaries stay far below the 8 (m, p) int32 products
+    that a stacked reduction holds (34 GB a chip, more than the chip).
+    The compiler lowers each int32 reduce-scatter as an all-reduce of one
+    column block of the product and a slice of its rows."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import plan
+    mesh = Mesh(np.array(topo.devices), ("model",),
+                axis_types=(AxisType.Auto,))
+    m = n = p = 32768
+    cfg = parse_spec("ozimmu_h-auto:df32:fused@model/int32")
+    args = [jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=NamedSharding(
+                mesh, PartitionSpec(None, "model"))),
+            jax.ShapeDtypeStruct((n, p), jnp.float32, sharding=NamedSharding(
+                mesh, PartitionSpec("model", None)))]
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(lambda a, b: ozimmu_dot_general(
+            a, b, DN, cfg)).lower(*args).compile()
+    groups = plan.plan_contraction(cfg, m, n, p, a=args[0],
+                                   _record=False).highprec_adds
+    assert groups == 8
+    assert compiled.memory_analysis().temp_size_in_bytes < groups * m * p * 4
+    assert compiled.output_shardings.is_equivalent_to(
+        NamedSharding(mesh, PartitionSpec("model", None)), 2)
+    text = compiled.as_text()
+    _assert_kernel(text)
+    assert not re.search(r"s32\[8,32768,32768\]", text)
+
+
 @pytest.mark.parametrize("spec", ["ozimmu_h-auto", "oz2_h-auto:fast2"])
 def test_f64_xla_path_not_compiled_for_v5e(one_chip, spec):
     """The f64 XLA path (f64 operands, x64 on) does not compile for a v5e:
